@@ -1630,4 +1630,7 @@ if __name__ == "__main__":
         REPEATS = 20
         BATCHES = [1, 64, 256]
         RUNTIME_CLIENTS = [1, 8]
+    from repro import compile_cache
+
+    compile_cache.enable()
     run(args.sections or None)

@@ -218,6 +218,11 @@ def quadform_heads_q8(
     return quadform_heads_q8_xla(Z, M_q, col_scale, V, c, b, gamma, msq)
 
 
+# Every head-sharded scorer below is a shard_map with check_vma=False: the
+# per-shard primitive may be a pallas_call, whose out_shape carries no
+# varying-across-mesh-axes type, and check_vma=True refuses it.
+
+
 def quadform_heads_sharded(
     Z, M_all, V, c, b, gamma, msq, *, mesh, config: TileConfig | None = None
 ):
@@ -238,7 +243,6 @@ def quadform_heads_sharded(
     valid (n, K)); ``z_sq`` is a per-shard by-product and is not
     returned (the per-head validity mask already encodes it).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     axis = mesh.axis_names[0]
@@ -256,12 +260,13 @@ def quadform_heads_sharded(
         )
         return scores, valid
 
-    fn = shard_map(
+    fn = jax.shard_map(
         _local,
         mesh=mesh,
         in_specs=(P(), P(axis, None, None), P(axis, None),
                   P(axis), P(axis), P(axis), P(axis)),
         out_specs=(P(None, axis), P(None, axis)),
+        check_vma=False,
     )
     return fn(Z, M_all, V, c, b, gamma, msq)
 
@@ -282,7 +287,6 @@ def quadform_heads_q8_sharded(
     K must divide the axis size (pad validity-neutral heads first).
     Returns head-sharded (scores (n, K), valid (n, K)).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     axis = mesh.axis_names[0]
@@ -300,12 +304,13 @@ def quadform_heads_q8_sharded(
         )
         return scores, valid
 
-    fn = shard_map(
+    fn = jax.shard_map(
         _local,
         mesh=mesh,
         in_specs=(P(), P(axis, None, None), P(axis, None), P(axis, None),
                   P(axis), P(axis), P(axis), P(axis)),
         out_specs=(P(None, axis), P(None, axis)),
+        check_vma=False,
     )
     return fn(Z, M_q, col_scale, V, c, b, gamma, msq)
 
@@ -363,7 +368,6 @@ def rff_score_sharded(
     K must divide evenly by the axis size (pad heads first). Returns
     head-sharded scores (n, K), spec ``P(None, axis)``.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     axis = mesh.axis_names[0]
@@ -378,11 +382,12 @@ def rff_score_sharded(
     def _local(Zb, Wf, ph, ws, bs):
         return rff_score(Zb, Wf, ph, ws, bs, config=config)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         _local,
         mesh=mesh,
         in_specs=(P(), P(), P(), P(axis, None), P(axis)),
         out_specs=P(None, axis),
+        check_vma=False,
     )
     return fn(Z, W, phase, weights, bias)
 
@@ -436,7 +441,6 @@ def rff_score_q8_sharded(
     primitive. K must divide the axis size (pad heads first). Returns
     head-sharded scores (n, K), spec ``P(None, axis)``.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     axis = mesh.axis_names[0]
@@ -451,11 +455,12 @@ def rff_score_q8_sharded(
     def _local(Zb, Wf, ws, ph, wq, wts, bs):
         return rff_score_q8(Zb, Wf, ws, ph, wq, wts, bs, config=config)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         _local,
         mesh=mesh,
         in_specs=(P(), P(), P(), P(), P(axis, None), P(axis), P(axis)),
         out_specs=P(None, axis),
+        check_vma=False,
     )
     return fn(Z, W_q, w_scale, phase, weights_q, wt_scale, bias)
 
@@ -555,7 +560,6 @@ def fastfood_score_sharded(
     only O(K) memory in the artifact — is the same. K must divide the
     axis size (pad heads first). Returns head-sharded scores (n, K).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     axis = mesh.axis_names[0]
@@ -570,11 +574,12 @@ def fastfood_score_sharded(
     def _local(Zb, Bs, Gs, ps, ss, ph, ws, bs):
         return fastfood_score(Zb, Bs, Gs, ps, ss, ph, ws, bs, config=config)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         _local,
         mesh=mesh,
         in_specs=(P(), P(), P(), P(), P(), P(), P(axis, None), P(axis)),
         out_specs=P(None, axis),
+        check_vma=False,
     )
     return fn(Z, B, G, perm, scale, phase, weights, bias)
 
@@ -591,7 +596,6 @@ def fastfood_score_q8_sharded(
     ``rff_score_q8_sharded``. K must divide the axis size (pad heads
     first). Returns head-sharded scores (n, K).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     axis = mesh.axis_names[0]
@@ -608,12 +612,13 @@ def fastfood_score_q8_sharded(
             Zb, bq, gq, ps, sq, ssc, ph, wq, wts, bs, config=config
         )
 
-    fn = shard_map(
+    fn = jax.shard_map(
         _local,
         mesh=mesh,
         in_specs=(P(), P(), P(), P(), P(), P(), P(),
                   P(axis, None), P(axis), P(axis)),
         out_specs=P(None, axis),
+        check_vma=False,
     )
     return fn(
         Z, b_q, g_q, perm, s_q, stack_scale, phase, weights_q, wt_scale, bias
@@ -643,20 +648,27 @@ def family_scores(artifact, Z, *, config: TileConfig | None = None):
 
 
 def rbf_scores_xla(Z, X, alpha_y, gamma, b):
-    """Exact expansion via the GEMM distance trick (what XLA fuses well)."""
+    """Exact expansion via the GEMM distance trick (what XLA fuses well),
+    in f32 at HIGHEST like the ``rbf_pred`` kernel."""
+    hi = jax.lax.Precision.HIGHEST
     sq_z = jnp.sum(Z * Z, axis=-1)[:, None]
     sq_x = jnp.sum(X * X, axis=-1)[None, :]
-    d2 = jnp.maximum(sq_z + sq_x - 2.0 * (Z @ X.T), 0.0)
-    return jnp.exp(-gamma * d2) @ alpha_y + b
+    d2 = jnp.maximum(sq_z + sq_x - 2.0 * jnp.dot(Z, X.T, precision=hi), 0.0)
+    kmat = jnp.exp(-gamma * d2)
+    if alpha_y.ndim == 2:                                   # (K, m) heads
+        return jnp.dot(kmat, alpha_y.T, precision=hi) + jnp.reshape(b, (1, -1))
+    return jnp.dot(kmat, alpha_y, precision=hi) + b
 
 
 def rbf_scores(Z, X, alpha_y, gamma, b, *, config: TileConfig | None = None):
     """Dispatching exact decision values f(Z) = sum_i a_i K(x_i, z) + b.
 
-    The Pallas path streams double-buffered SV tiles flash-attention-style
-    (never materializes the (n, n_sv) kernel matrix in HBM).
-    ``config=None`` resolves the tuned (or default) ``TileConfig`` for
-    this (d, m, n) bucket from the tuning registry.
+    ``alpha_y`` (m,) with a scalar ``b`` gives (n,); (K, m) heads sharing
+    the SVs, with ``b`` scalar or (K,), give (n, K) from ONE pass over the
+    SVs. The Pallas path streams double-buffered SV tiles
+    flash-attention-style (never materializes the (n, n_sv) kernel matrix
+    in HBM). ``config=None`` resolves the tuned (or default)
+    ``TileConfig`` for this (d, m, n) bucket from the tuning registry.
     """
     if config is None:
         config = tuning.lookup(

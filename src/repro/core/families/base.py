@@ -38,6 +38,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.rbf import rbf_kernel
+
 Array = jax.Array
 
 # Bump when the on-disk layout changes incompatibly. Readers accept
@@ -213,3 +215,18 @@ def stack_heads(svm) -> tuple[Array, Array, int, bool]:
     ay2 = ay if multiclass else ay[None, :]
     b = jnp.reshape(svm.b, (ay2.shape[0],))
     return ay2, b, ay2.shape[0], multiclass
+
+
+def exact_scores(svm, Z) -> Array:
+    """(n, K) exact decision values of every head of ``svm`` on ``Z``.
+
+    The reference every approximation is judged against (``compile_model``,
+    fourier's held-out verdict, the ``DriftGuard`` canary), so it runs in
+    f32 at "highest" matmul precision: a TPU's default f32 matmul rounds
+    its operands to bf16, and with a small gamma every kernel value sits
+    near 1, where bf16 keeps two or three digits.
+    """
+    ay2, b, _, _ = stack_heads(svm)
+    with jax.default_matmul_precision("highest"):
+        kmat = rbf_kernel(jnp.asarray(Z, jnp.float32), svm.X, svm.gamma)
+        return kmat @ ay2.T + b[None, :]

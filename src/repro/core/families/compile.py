@@ -24,8 +24,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.families.base import CompiledArtifact, stack_heads
-from repro.core.rbf import SVMModel, rbf_kernel
+from repro.core.families.base import CompiledArtifact, exact_scores, stack_heads
+from repro.core.rbf import SVMModel
 from repro.kernels.common import autotune
 
 
@@ -120,8 +120,8 @@ def compile_model(
         sample = _families.fourier.holdout_sample(svm, seed, sample_n)
     Z = jnp.asarray(np.asarray(sample, np.float32))
 
-    ay2, b, k_heads, _ = stack_heads(svm)
-    exact = rbf_kernel(Z, svm.X, svm.gamma) @ ay2.T + b[None, :]   # (n, K)
+    k_heads = stack_heads(svm)[2]
+    exact = exact_scores(svm, Z)                                    # (n, K)
     exact_scale = float(jnp.mean(jnp.abs(exact)))
     limit = budget.limit(exact_scale)
 

@@ -40,9 +40,10 @@ from repro.core.families.base import (
     PAD_HEAD_BIAS,
     CompiledArtifact,
     base_meta,
+    exact_scores,
     stack_heads,
 )
-from repro.core.rbf import SVMModel, rbf_kernel
+from repro.core.rbf import SVMModel
 from repro.kernels.common import TileConfig, tuning
 from repro.kernels.fwht import ref as _fwht_ref
 
@@ -132,7 +133,7 @@ def compile(                                                   # noqa: A001
     # points and ship the verdict with the artifact. For int8 the verdict
     # is measured on the QUANTIZED artifact — the accuracy contract must
     # describe the arrays being served, not their f32 parent.
-    exact = rbf_kernel(Zh, jnp.asarray(X), svm.gamma) @ ay2.T + b[None, :]
+    exact = exact_scores(svm, Zh)
     approx, _ = score(art, Zh)
     err = jnp.abs(approx - exact)
     mean_err = float(jnp.mean(err))

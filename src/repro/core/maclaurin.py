@@ -63,9 +63,12 @@ def approximate(model: SVMModel) -> ApproxModel:
     base = ay * jnp.exp(-gamma * sv_sq_norms)                  # alpha_y e^{-g||x||^2}
     c = jnp.sum(base)
     w = 2.0 * gamma * base                                     # (n_sv,)
-    v = X.T @ w                                                # (d,)
+    # f32 at HIGHEST: a TPU's default matmul rounds to bf16, which costs
+    # the collapsed model ~7x its error on mnist (compile time only)
+    hi = jax.lax.Precision.HIGHEST
+    v = jnp.dot(X.T, w, precision=hi)                          # (d,)
     dvals = 2.0 * gamma**2 * base                              # D diagonal
-    M = jnp.einsum("i,ij,ik->jk", dvals, X, X)                 # X^T D X
+    M = jnp.einsum("i,ij,ik->jk", dvals, X, X, precision=hi)   # X^T D X
     return ApproxModel(
         c=c,
         v=v,

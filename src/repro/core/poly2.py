@@ -107,8 +107,9 @@ def collapse_rbf_as_poly2(model) -> ApproxModel:
     sv_sq = jnp.sum(X * X, axis=-1)
     a2 = equivalent_poly2_alphas(model.alpha_y, sv_sq, gamma)
     c = jnp.sum(a2)
-    v = X.T @ (2.0 * gamma * a2)
-    M = jnp.einsum("i,ij,ik->jk", gamma**2 * a2, X, X)
+    hi = jax.lax.Precision.HIGHEST          # as in maclaurin.approximate
+    v = jnp.dot(X.T, 2.0 * gamma * a2, precision=hi)
+    M = jnp.einsum("i,ij,ik->jk", gamma**2 * a2, X, X, precision=hi)
     return ApproxModel(
         c=c,
         v=v,

@@ -17,6 +17,7 @@ the technique's complexity depends on) so tests/benchmarks stay CPU-feasible.
 from __future__ import annotations
 
 import dataclasses
+import zlib
 from typing import Callable
 
 import numpy as np
@@ -84,7 +85,8 @@ def make_dataset(
 ) -> tuple[Array, Array, Array, Array, DatasetSpec]:
     """Returns (X_train, y_train, X_test, y_test, spec); labels in {-1,+1}."""
     spec = DATASETS[name]
-    rng = np.random.default_rng(seed + hash(name) % 2**31)
+    # crc32, not hash(): str hashes are salted per process
+    rng = np.random.default_rng(seed + zlib.crc32(name.encode()) % 2**31)
     n_tr = max(64, int(spec.n_train * scale))
     n_te = max(64, int(spec.n_test * scale))
     X = _features(rng, n_tr + n_te, spec.d, spec.feature_kind)

@@ -33,9 +33,18 @@ needs no padding by construction. d' < 128 lanes (models with d <= 64)
 compiles but underfills the lane tile — small-d models should prefer the
 dense path anyway (d^2 is tiny there).
 
-The permutation is applied with ``jnp.take`` along the lane axis against
-the resident int32 index rows — supported natively in interpret mode and
-by Mosaic's dynamic-gather lowering on current TPU toolchains.
+Mosaic lowering: inside the kernel the butterfly stages are lane
+rotations (``pltpu.roll``) plus a select on the lane index's bit h
+(``_fwht_lanes``) — the reshape/concat schedule of ``ref.fwht`` does not
+lower for h < 128. Mosaic has no lane gather across vregs (``jnp.take``
+along the lanes is refused with "Shape mismatch in input, indices and
+output"), so the permutation runs on the MXU (``_permute_lanes``): a 0/1
+one-hot built in VMEM from the int32 index row, 128 output lanes at a
+time, against the tile split into three bf16 parts (hi + mid + lo is
+the f32 value exactly). Every output element is one nonzero product per
+part, so the gather is exact — bit-identical to ``jnp.take``. That costs
+3 * 2 * BN * d'^2 MXU flops per stack on top of the O(F log d')
+butterflies; the transforms themselves stay O(F log d').
 
 Block sizes come from ``TileConfig.block_n``, resolved per shape bucket
 by the tuning registry under the ``fwht`` / ``fwht_q8`` kernel names.
@@ -46,32 +55,79 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import TileConfig, tiles, tuning
-from repro.kernels.fwht.ref import fwht
 
 
-def _transform(z, B, G, P, S):
+def _fwht_lanes(x):
+    """``ref.fwht`` over the lanes of a (BN, d') tile, in the same
+    floating-point order: at stage h, lanes with bit h clear take
+    x[i] + x[i + h] and the others x[i - h] - x[i]."""
+    d = x.shape[-1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    h = 1
+    while h < d:
+        up = pltpu.roll(x, d - h, 1)            # up[i] = x[i + h]
+        down = pltpu.roll(x, h, 1)              # down[i] = x[i - h]
+        x = jnp.where((lane & h) == 0, x + up, down - x)
+        h *= 2
+    return x
+
+
+def _permute_lanes(t, p_ref, s):
+    """t[:, perm[s]] for a (BN, d') f32 tile; ``p_ref`` holds the
+    (stacks, d') int32 index rows (each 128-lane piece is loaded straight
+    from VMEM, so it broadcasts down the one-hot's sublanes).
+
+    Exact: t = hi + mid + lo with each part bf16, and every column of the
+    0/1 one-hot holds a single 1, so each bf16 GEMM returns its part's
+    gathered values unrounded and the f32 sum rebuilds t exactly.
+    """
+    d = t.shape[-1]
+    hi = t.astype(jnp.bfloat16)
+    rest = t - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    width = min(d, tiles.LANE)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (d, width), 0)
+    cols = []
+    for c in range(0, d, width):
+        perm = p_ref[pl.ds(s, 1), pl.ds(c, width)]                 # (1, W)
+        onehot = (rows == perm).astype(jnp.bfloat16)                  # (d', W)
+
+        def gather(part, onehot=onehot):
+            return jax.lax.dot_general(
+                part, onehot, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+
+        cols.append((gather(hi) + gather(mid)) + gather(lo))
+    return jnp.concatenate(cols, axis=-1) if len(cols) > 1 else cols[0]
+
+
+def _transform(z, B, G, p_ref, S):
     """The per-stack structured transform on a resident (BN, d') tile.
 
     Static Python loop over stacks — each iteration is 2 log2(d')
-    butterfly stages + 3 diagonal multiplies + 1 lane gather, all VPU
-    work on VMEM-resident data. Returns the concatenated (BN, F) block
-    in the same stack-major feature order as ``ref.fastfood_project``.
+    butterfly stages + 3 diagonal multiplies on the VPU and one exact
+    permutation on the MXU, all on VMEM-resident data. Returns the
+    concatenated (BN, F) block in the same stack-major feature order as
+    ``ref.fastfood_project``.
     """
     projs = []
     for s in range(B.shape[0]):
-        t = fwht(z * B[s][None, :])
-        t = jnp.take(t, P[s], axis=1)
-        t = fwht(t * G[s][None, :])
-        projs.append(t * S[s][None, :])
+        t = _fwht_lanes(z * B[s:s + 1, :])
+        t = _permute_lanes(t, p_ref, s)
+        t = _fwht_lanes(t * G[s:s + 1, :])
+        projs.append(t * S[s:s + 1, :])
     return jnp.concatenate(projs, axis=-1)
 
 
 def _kernel(z_ref, b_ref, g_ref, p_ref, s_ref, ph_ref, wt_ref, bias_ref, o_ref):
     z = z_ref[...]                           # (BN, d') f32
     proj = _transform(
-        z, b_ref[...], g_ref[...], p_ref[...], s_ref[...]
+        z, b_ref[...], g_ref[...], p_ref, s_ref[...]
     )                                        # (BN, F), never leaves VMEM
     phi = jnp.cos(proj + ph_ref[...][None, :])
     scores = jax.lax.dot_general(
@@ -93,7 +149,7 @@ def _kernel_q8(z_ref, b_ref, g_ref, p_ref, s_ref, ss_ref, ph_ref,
     G = g_ref[...].astype(jnp.float32)
     ss = ss_ref[...]                         # (stacks,) combined G*S scales
     S = s_ref[...].astype(jnp.float32) * ss[:, None]
-    proj = _transform(z, B, G, p_ref[...], S)
+    proj = _transform(z, B, G, p_ref, S)
     phi = jnp.cos(proj + ph_ref[...][None, :])
     scores = jax.lax.dot_general(
         phi, wt_ref[...].astype(jnp.float32), (((1,), (1,)), ((), ())),

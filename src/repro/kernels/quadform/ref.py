@@ -16,13 +16,16 @@ import jax.numpy as jnp
 def eq311_valid(z_sq, gamma, msq):
     """Per-head Eq 3.11 mask (n, K): valid iff ||x_M||^2 ||z||^2 < 1/(16 g^2).
 
-    z_sq: (n,), gamma/msq: (K,). The single definition shared by the Pallas
-    kernel, the XLA backend path and the vmap oracle (plain jnp so it can
-    run inside a kernel body). The max() guards gamma == 0 (degenerate
-    head) without producing inf.
+    z_sq: (n,) or an (n, 1) column, gamma/msq: (K,) or (1, K) rows. The
+    single definition shared by the Pallas kernel (which stays 2-D), the
+    XLA backend path and the vmap oracle (plain jnp so it can run inside a
+    kernel body). The max() guards gamma == 0 (degenerate head) without
+    producing inf.
     """
+    z_col = z_sq if z_sq.ndim == 2 else z_sq[:, None]
+    gamma, msq = gamma.reshape(1, -1), msq.reshape(1, -1)
     rhs = 0.0625 / jnp.maximum(gamma * gamma, 1e-30)
-    return msq[None, :] * z_sq[:, None] < rhs[None, :]
+    return msq * z_col < rhs
 
 
 def quadform_predict_ref(Z, M, v, c, b, gamma):

@@ -27,19 +27,9 @@ from dataclasses import dataclass, field
 
 
 def normalize_cost_analysis(ca) -> dict:
-    """Normalize ``Compiled.cost_analysis()`` across jax versions.
-
-    Older jax returned a per-device LIST of properties dicts (sometimes
-    empty), current jax returns the dict directly; ``None`` shows up on
-    backends without a cost model. Callers always want one flat dict —
-    ``{}`` when nothing is available — so indexing like ``ca["flops"]``
-    never dies with "list indices must be integers".
-    """
-    if ca is None:
-        return {}
-    if isinstance(ca, (list, tuple)):
-        return dict(ca[0]) if ca else {}
-    return dict(ca)
+    """``Compiled.cost_analysis()`` as a plain dict: ``{}`` on a backend
+    without a cost model, where it returns ``None``."""
+    return dict(ca) if ca else {}
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,

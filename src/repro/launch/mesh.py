@@ -16,12 +16,8 @@ import jax
 
 
 def _make(shape: tuple[int, ...], axes: tuple[str, ...]):
-    # axis_types only exists on jax >= 0.5 (and Auto is its default there);
-    # 0.4.x make_mesh takes no such kwarg.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
+    auto = (jax.sharding.AxisType.Auto,) * len(axes)
+    return jax.make_mesh(shape, axes, axis_types=auto)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

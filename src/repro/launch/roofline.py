@@ -37,6 +37,9 @@ import glob
 import json
 import os
 
+# TPU v5e bf16 peak and HBM bandwidth. Ranking constants only: they order
+# tile and family candidates and never stand for a measured rate. A peak
+# table keyed by ``device_kind`` belongs with the chip benchmark.
 PEAK_FLOPS = 197e12
 HBM_BW = 819e9
 ICI_BW = 50e9

@@ -52,12 +52,10 @@ import dataclasses
 import threading
 import time
 
-import jax.numpy as jnp
 import numpy as np
 
 from repro.core.families import compile_model
-from repro.core.families.base import stack_heads
-from repro.core.rbf import rbf_kernel
+from repro.core.families.base import exact_scores, stack_heads
 from repro.serve.runtime.publish import PublishSpec
 
 
@@ -108,10 +106,8 @@ class ReservoirSampler:
 
 def _exact_labels(exact, Z: np.ndarray) -> np.ndarray:
     """Ground-truth labels from the exact RBF expansion (the canary judge)."""
-    ay2, b, _, multiclass = stack_heads(exact)
-    K = rbf_kernel(jnp.asarray(Z), exact.X, exact.gamma)       # (n, n_sv)
-    scores = np.asarray(K @ ay2.T + b)                          # (n, K)
-    if multiclass:
+    scores = np.asarray(exact_scores(exact, Z))                 # (n, K)
+    if stack_heads(exact)[3]:                                   # multiclass
         return np.argmax(scores, axis=1)
     return np.where(scores[:, 0] >= 0, 1, -1)
 

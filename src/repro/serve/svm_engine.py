@@ -20,9 +20,8 @@ Shape buckets, bounded jit cache
   power-of-two bucket (floored at ``min_bucket``, capped at ``max_batch``
   — longer batches are chunked), so the engine owns at most
   log2(max_batch / min_bucket) + 1 compiled variants and steady-state
-  serving performs ZERO recompilations. The padded input buffer is donated
-  to the compiled step (no-op on CPU where buffer sizes can't alias; lets
-  XLA reuse the buffer on device backends).
+  serving performs ZERO recompilations. The padded input is not donated:
+  no output has its (bucket, d) shape, so there is nothing to alias it to.
 
 Per-bucket tile tuning
   Each bucket resolves its own ``TileConfig`` at trace time from the
@@ -353,13 +352,12 @@ class SVMEngine:
         self.head_mesh = head_mesh
 
         # The artifact's arrays are closed over -> baked into the executable
-        # as constants; only the padded batch is an argument (and is donated
-        # where the backend supports aliasing). Under a head_mesh the heads
-        # are padded up to the mesh axis size and the family's sharded
-        # scorer partitions them across devices; the padded artifact is
-        # engine-internal (padding would change the content digest) and
-        # ``num_heads`` keeps the REAL head count — ``_finalize`` slices
-        # the score columns back down.
+        # as constants; only the padded batch is an argument. Under a
+        # head_mesh the heads are padded up to the mesh axis size and the
+        # family's sharded scorer partitions them across devices; the
+        # padded artifact is engine-internal (padding would change the
+        # content digest) and ``num_heads`` keeps the REAL head count —
+        # ``_finalize`` slices the score columns back down.
         if head_mesh is not None:
             pad = getattr(self._family, "pad_heads", None)
             sharded = getattr(self._family, "score_sharded", None)
@@ -389,8 +387,7 @@ class SVMEngine:
                 labels = jnp.where(scores[:, 0] >= 0, 1, -1)
             return scores, valid_row, labels
 
-        donate = (0,) if jax.default_backend() != "cpu" else ()
-        self._step = jax.jit(_step, donate_argnums=donate)
+        self._step = jax.jit(_step)
         self._slow = self._build_slow(exact, mesh) if exact is not None else None
 
         # Degraded-mode step (circuit breaker open): the exact expansion
@@ -555,10 +552,12 @@ class SVMEngine:
     def _build_slow(self, exact: SVMModel, mesh: Mesh | None):
         """Exact re-scorer through the streaming rbf_pred backend path.
 
-        With a mesh, SVs are sharded over its first axis (rows padded with
-        alpha = 0, which contribute exactly 0) and partial sums psum'd.
-        Multiclass exact models keep alpha_y as (K, n_sv); heads are
-        vmapped — the slow path is off the latency budget by definition.
+        Every head shares ONE pass over the SVs (alpha_y as (K, n_sv)).
+        Without a mesh the SVs live on this engine's device, so a
+        replica's fallback and degraded rows are scored where its fast
+        path runs. With a mesh, SVs are sharded over its first axis (rows
+        padded with alpha = 0, which contribute exactly 0) and partial
+        sums psum'd.
         """
         ay = np.asarray(exact.alpha_y, np.float32)
         ay2 = ay[None, :] if ay.ndim == 1 else ay           # (K, n_sv)
@@ -566,14 +565,11 @@ class SVMEngine:
         gamma, bias = exact.gamma, exact.b
 
         if mesh is None:
-            Xd, ayd = jnp.asarray(X), jnp.asarray(ay2)
+            Xd, ayd = self._put(X), self._put(ay2)
 
             @jax.jit
             def slow(Zb):
-                f = jax.vmap(
-                    lambda a: backend.rbf_scores(Zb, Xd, a, gamma, 0.0)
-                )(ayd)                                       # (K, m)
-                return f.T + jnp.reshape(bias, (1, -1))      # (m, K)
+                return backend.rbf_scores(Zb, Xd, ayd, gamma, bias)  # (m, K)
 
             return slow
 
@@ -585,22 +581,22 @@ class SVMEngine:
         Xd = jax.device_put(Xp)
         ayd = jax.device_put(ayp)
 
-        from jax.experimental.shard_map import shard_map
-
         def _partial(Zb, Xs, ays):
-            f = jax.vmap(lambda a: backend.rbf_scores(Zb, Xs, a, gamma, 0.0))(ays)
-            return jax.lax.psum(f, axis)                     # (K, m) replicated
+            f = backend.rbf_scores(Zb, Xs, ays, gamma, 0.0)  # (m, K)
+            return jax.lax.psum(f, axis)                     # replicated
 
-        sharded = shard_map(
+        # check_vma=False: the Pallas rbf_pred call carries no vma type
+        sharded = jax.shard_map(
             _partial,
             mesh=mesh,
             in_specs=(P(), P(axis, None), P(None, axis)),
             out_specs=P(),
+            check_vma=False,
         )
 
         @jax.jit
         def slow(Zb):
-            return sharded(Zb, Xd, ayd).T + jnp.reshape(bias, (1, -1))
+            return sharded(Zb, Xd, ayd) + jnp.reshape(bias, (1, -1))
 
         return slow
 
@@ -625,7 +621,7 @@ class SVMEngine:
         if Z is not None and self.allow_fallback and not valid.all():
             idx = np.nonzero(~valid)[0]
             self.stats.record_fallback(len(idx))
-            exact_scores = np.asarray(self._slow(jnp.asarray(Z[idx])))  # (m, K)
+            exact_scores = np.asarray(self._slow(self._put(Z[idx])))  # (m, K)
             scores[idx] = exact_scores
             if self.multiclass:
                 labels[idx] = exact_scores.argmax(axis=-1)

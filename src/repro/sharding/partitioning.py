@@ -32,16 +32,12 @@ MeshAxes = tuple[str, ...] | str | None
 
 
 def abstract_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """Version-compatible AbstractMesh constructor.
+    """A device-free mesh of the given axis sizes and names.
 
-    jax >= 0.5 takes (axis_sizes, axis_names); 0.4.x takes a single tuple
-    of (name, size) pairs. Rule/spec logic only needs names and sizes, not
-    real devices, so tests build meshes through this.
+    Rule/spec logic only needs names and sizes, not real devices, so
+    tests build meshes through this.
     """
-    try:
-        return jax.sharding.AbstractMesh(tuple(shape), tuple(axes))
-    except TypeError:
-        return jax.sharding.AbstractMesh(tuple(zip(axes, shape)))
+    return jax.sharding.AbstractMesh(tuple(shape), tuple(axes))
 
 
 @dataclasses.dataclass(frozen=True)

@@ -8,6 +8,7 @@ rank Fastfood against dense RFF."""
 import warnings
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -86,6 +87,31 @@ def test_fwht_xla_matches_butterfly(d):
         np.asarray(fwht_xla(jnp.asarray(x))), np.asarray(fwht(jnp.asarray(x))),
         rtol=1e-5, atol=1e-4,
     )
+
+
+@pytest.mark.parametrize("d", [128, 512])
+def test_kernel_transform_pieces_are_bit_exact(d):
+    # Inside the kernel the butterfly is lane rotations + a select and the
+    # permutation a split-bf16 one-hot GEMM (what Mosaic lowers); both must
+    # reproduce ref.fwht and jnp.take bit for bit.
+    from jax.experimental import pallas as pl
+
+    from repro.kernels.fwht.kernel import _fwht_lanes, _permute_lanes
+
+    rng = np.random.default_rng(d)
+    x = jnp.asarray(rng.standard_normal((16, d)).astype(np.float32) * 3.0)
+    perm = jnp.asarray(rng.permutation(d).astype(np.int32)[None, :])
+
+    def body(x_ref, p_ref, h_ref, g_ref):
+        h_ref[...] = _fwht_lanes(x_ref[...])
+        g_ref[...] = _permute_lanes(x_ref[...], p_ref, 0)
+
+    h, g = pl.pallas_call(
+        body, out_shape=[jax.ShapeDtypeStruct(x.shape, jnp.float32)] * 2,
+        interpret=True,
+    )(x, perm)
+    np.testing.assert_array_equal(np.asarray(h), np.asarray(fwht(x)))
+    np.testing.assert_array_equal(np.asarray(g), np.asarray(x)[:, np.asarray(perm)[0]])
 
 
 def test_fastfood_project_pads_nonpow2_d_exactly():

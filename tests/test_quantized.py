@@ -12,6 +12,7 @@ from repro.core import Budget, CompiledArtifact, backend, compile_model, gamma_m
 from repro.core.families import FAMILIES, get_family, quantize, score_artifact
 from repro.core.families.base import ARTIFACT_FORMAT_VERSION
 from repro.core.rbf import SVMModel
+from repro.serve import PublishSpec
 from repro.serve.svm_engine import SVMEngine
 
 NUM_FEATURES = 256          # small fourier basis keeps the suite fast
@@ -273,7 +274,7 @@ def test_registry_evicts_and_reloads_quantized_artifact(tmp_path):
         warmup_on_load=False,
     )
     d_q8 = reg.add_file(path, alias="det-int8")
-    d_f32 = reg.register(f32, alias="det-f32")
+    d_f32 = reg.register(f32, PublishSpec(alias="det-f32"))
     assert d_q8 == q8.digest() != d_f32       # variants are distinct entries
 
     Z = np.random.default_rng(17).standard_normal((16, 24)).astype(np.float32) * 0.3

@@ -361,7 +361,7 @@ def test_reload_after_evict_reverifies_sha(tmp_path):
                            memory_budget_bytes=1)    # evict everything cold
     reg.add_file(path, alias="m@latest")
     other = maclaurin.compile(_svm(14), dtype="float32")
-    d2 = reg.register(other, alias="other@latest")
+    d2 = reg.register(other, PublishSpec(alias="other@latest"))
     _, e1 = reg.get_engine("m@latest")               # load #1 verifies + serves
     reg.get_engine("other@latest")                   # budget=1 evicts "m"
     assert reg.eviction_count >= 1

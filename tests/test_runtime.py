@@ -47,8 +47,8 @@ def _batches(rng, count, d=8, lo=1, hi=5):
 def test_registry_dedupes_identical_compiles():
     m = _svm(3)
     reg = ArtifactRegistry(warmup_on_load=False, engine_opts=ENGINE_OPTS)
-    d1 = reg.register(maclaurin.compile(m), alias="a@latest")
-    d2 = reg.register(maclaurin.compile(m), alias="b@latest")
+    d1 = reg.register(maclaurin.compile(m), PublishSpec(alias="a@latest"))
+    d2 = reg.register(maclaurin.compile(m), PublishSpec(alias="b@latest"))
     assert d1 == d2
     snap = reg.snapshot()
     assert snap["models"] == 1
@@ -62,7 +62,7 @@ def test_registry_dedupes_identical_compiles():
 
 def test_registry_ref_resolution():
     reg = ArtifactRegistry(warmup_on_load=False, engine_opts=ENGINE_OPTS)
-    digest = reg.register(maclaurin.compile(_svm(3)), alias="det@latest")
+    digest = reg.register(maclaurin.compile(_svm(3)), PublishSpec(alias="det@latest"))
     assert reg.resolve(digest) == digest
     assert reg.resolve("det@latest") == digest
     assert reg.resolve("det") == digest            # @latest convention
@@ -130,8 +130,8 @@ def test_registry_in_memory_entry_never_loses_arrays():
     arts = [maclaurin.compile(_svm(s)) for s in (1, 2)]
     reg = ArtifactRegistry(memory_budget_bytes=arts[0].nbytes() + 8,
                            warmup_on_load=False, engine_opts=ENGINE_OPTS)
-    d0 = reg.register(arts[0], alias="m0")
-    reg.register(arts[1], alias="m1")
+    d0 = reg.register(arts[0], PublishSpec(alias="m0"))
+    reg.register(arts[1], PublishSpec(alias="m1"))
     reg.get_engine("m0")
     reg.get_engine("m1")
     assert reg.eviction_count == 1

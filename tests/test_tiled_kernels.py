@@ -53,13 +53,21 @@ def test_tile_arithmetic():
 
 
 def test_tileconfig_block_k_budget():
-    """block_k auto-resolution keeps the (d_pad, block_k*d_pad) f32 slice
-    under the VMEM budget, floored at one head."""
+    """block_k auto-resolution keeps the whole double-buffered grid step
+    (Hessian block, Z tile, per-head rows, outputs, temporaries) under the
+    scoped-VMEM budget, floored at one head, evened out over its blocks."""
     d_pad = 896                                  # mnist d=784 lane-padded
-    cfg = TileConfig(vmem_limit_mb=8)
+    cfg = TileConfig(block_n=256, vmem_limit_mb=32)
     bk = cfg.resolve_block_k(10, d_pad)
-    assert bk * d_pad * d_pad * 4 <= 8 << 20
-    assert (bk + 1) * d_pad * d_pad * 4 > 8 << 20     # largest that fits
+    assert cfg.quadform_vmem_bytes(bk, d_pad) <= 32 << 20
+    assert cfg.quadform_vmem_bytes(bk + 1, d_pad) > 32 << 20   # largest that fits
+    assert 2 * bk * d_pad * d_pad * 4 <= 32 << 20   # the double buffer counts
+    # int8 Hessian blocks are a quarter the size: more heads per block
+    assert cfg.resolve_block_k(10, d_pad, m_itemsize=1, per_head_rows=2) > bk
+    # 9 heads fit but 10 do not: two blocks of 5, not 9 + 1 padded to 18
+    cfg9 = TileConfig(block_n=8, vmem_limit_mb=58)
+    assert cfg9.quadform_vmem_bytes(10, d_pad) > 58 << 20 >= cfg9.quadform_vmem_bytes(9, d_pad)
+    assert cfg9.resolve_block_k(10, d_pad) == 5
     # one head over budget still runs (smallest possible tile)
     assert TileConfig(vmem_limit_mb=1).resolve_block_k(10, 2048) == 1
     # explicit block_k wins, capped at K
@@ -250,6 +258,29 @@ def test_rbf_pred_pallas_vs_xla_paths_agree():
     )
     f_x = backend.rbf_scores_xla(Z, X, a, 0.07, 0.3)
     np.testing.assert_allclose(np.asarray(f_p), np.asarray(f_x), rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("K", [3, 10])
+def test_rbf_pred_heads_share_one_sv_pass(K):
+    """(K, m) alpha_y rows score every head in one pass: each column
+    equals that head's single-head call, with per-head biases."""
+    n, m, d = 70, 300, 37
+    rng = np.random.default_rng(K)
+    Z = jnp.asarray(rng.standard_normal((n, d)).astype(np.float32))
+    X = jnp.asarray(rng.standard_normal((m, d)).astype(np.float32))
+    a = jnp.asarray(rng.standard_normal((K, m)).astype(np.float32))
+    b = jnp.asarray(rng.standard_normal(K).astype(np.float32))
+    cfg = TileConfig(block_n=32, block_m=128)
+    f_p = rbf_predict_pallas(Z, X, a, 0.07, b, config=cfg, interpret=True)
+    assert f_p.shape == (n, K)
+    np.testing.assert_allclose(
+        np.asarray(f_p), np.asarray(backend.rbf_scores_xla(Z, X, a, 0.07, b)),
+        rtol=2e-5, atol=2e-5,
+    )
+    for k in range(K):
+        one = rbf_predict_pallas(Z, X, a[k], 0.07, b[k], config=cfg, interpret=True)
+        np.testing.assert_allclose(np.asarray(f_p[:, k]), np.asarray(one),
+                                   rtol=1e-6, atol=1e-6)
 
 
 def test_backend_dispatch_routes_to_pallas(monkeypatch):

@@ -14,7 +14,10 @@ differs (it also checks the int8 digest actually differs from the f32
 one, so the quantized variants stay distinct registry entries).
 
 Usage: ``python tools/check_artifact_determinism.py`` (spawns its own
-children; needs ``src`` importable or on PYTHONPATH).
+children; needs ``src`` importable or on PYTHONPATH). The parent imports
+only ``os``/``subprocess``/``sys`` and must never touch JAX: on a TPU
+host a process that has touched JAX holds the chip, and the children
+could then not reach it.
 """
 
 from __future__ import annotations
