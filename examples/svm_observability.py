@@ -6,9 +6,9 @@ layers, all shown here end to end:
 1. **Tracing** — every submitted request gets a deterministic trace id
    (``{seed:04x}-{ordinal:012x}``, a seeded counter — replayable, never
    wall-clock); its lifecycle lands as linked spans (admission, queue
-   wait, coalesced engine step with bucket/TileConfig/recompile flag,
-   scatter, sync, verdict) in a bounded per-model ring, exportable as
-   JSONL. Monotone span counts survive ring eviction, so the
+   wait, the coalesced engine step from dispatch until its outputs are
+   on the host, with bucket/TileConfig/recompile flag, verdict) in a
+   bounded per-model ring, exportable as JSONL. Monotone span counts survive ring eviction, so the
    conservation identity (served + failed + expired + closed ==
    admitted) is checkable forever.
 
@@ -20,9 +20,10 @@ layers, all shown here end to end:
    EWMA step time are first-class series.
 
 3. **Profiling** — ``Runtime.profile(model, Z, path)`` wraps one
-   coalesced step in ``jax.profiler.trace`` with named annotations
-   around the engine step and the backend kernel-dispatch seam, for
-   TensorBoard / Perfetto inspection.
+   coalesced step in ``jax.profiler.trace`` with a named host span
+   around each stage of the flush (``runtime.flush`` and its assemble,
+   pad, put, step and resolve, then ``svm_engine.sync``) on the device
+   trace's clock, for TensorBoard / Perfetto inspection.
 
     PYTHONPATH=src python examples/svm_observability.py
 """
@@ -89,6 +90,7 @@ def main():
         step = obs.tracer.spans(key, "engine.step")[-1]
         print(
             f"[obs] last engine step: trace={step['trace_id']} "
+            f"dispatch-to-host={(step['t_end'] - step['t_start']) * 1e3:.2f} ms "
             f"bucket={step['attrs']['bucket']} "
             f"recompiled={step['attrs']['recompiled']} "
             f"tile={step['attrs']['tile_config']}"

@@ -96,34 +96,6 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-# ------------------------------------------------------- profiling seam
-#
-# The serving layer's observability package can install a trace-time
-# scope factory (jax.named_scope) here so scoring functions traced while
-# profiling is enabled carry structured op names in profiler timelines.
-# A callback hook keeps the layering clean: repro.core never imports
-# repro.serve. When unset, _scope is a no-op nullcontext.
-
-_profile_scope = None
-
-
-def set_profile_scope(factory) -> None:
-    """Install (or clear, with None) a ``name -> context manager`` factory
-    wrapped around the top-level dispatch seams (``family_scores``,
-    ``rbf_scores``). Installed by ``repro.serve.runtime.obs.profile``."""
-    global _profile_scope
-    _profile_scope = factory
-
-
-def _scope(name: str):
-    factory = _profile_scope
-    if factory is None:
-        import contextlib
-
-        return contextlib.nullcontext()
-    return factory(name)
-
-
 # --------------------------------------------------------------- quadform
 
 
@@ -640,8 +612,7 @@ def family_scores(artifact, Z, *, config: TileConfig | None = None):
     """
     from repro.core import families
 
-    with _scope(f"repro.backend/family_scores/{artifact.family}"):
-        return families.score_artifact(artifact, Z, config=config)
+    return families.score_artifact(artifact, Z, config=config)
 
 
 # -------------------------------------------------------------- exact RBF
@@ -675,10 +646,9 @@ def rbf_scores(Z, X, alpha_y, gamma, b, *, config: TileConfig | None = None):
             "rbf_pred",
             tuning.shape_key(d=Z.shape[1], m=X.shape[0], n=tuning.bucket(Z.shape[0])),
         )
-    with _scope("repro.backend/rbf_scores"):
-        if resolve() == "pallas":
-            return rbf_predict_pallas(
-                Z, X, alpha_y, gamma, b,
-                config=config, interpret=_interpret(),
-            )
-        return rbf_scores_xla(Z, X, alpha_y, gamma, b)
+    if resolve() == "pallas":
+        return rbf_predict_pallas(
+            Z, X, alpha_y, gamma, b,
+            config=config, interpret=_interpret(),
+        )
+    return rbf_scores_xla(Z, X, alpha_y, gamma, b)

@@ -206,6 +206,7 @@ def fastfood_score_pallas(
         out_specs=pl.BlockSpec((block_n, k_pad), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_pad, k_pad), jnp.float32),
         interpret=interpret,
+        name="fwht",
     )(
         Zp, B.astype(jnp.float32), G.astype(jnp.float32),
         perm.astype(jnp.int32), scale.astype(jnp.float32),
@@ -269,6 +270,7 @@ def fastfood_score_q8_pallas(
         out_specs=pl.BlockSpec((block_n, k_pad), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_pad, k_pad), jnp.float32),
         interpret=interpret,
+        name="fwht_q8",
     )(
         Zp, b_q.astype(jnp.int8), g_q.astype(jnp.int8),
         perm.astype(jnp.int32), s_q.astype(jnp.int8),

@@ -167,6 +167,7 @@ def _heads_call(kernel, Z, M, per_head, scalars, *, config, m_itemsize, interpre
             vmem_limit_bytes=config.vmem_limit_mb << 20
         ),
         interpret=interpret,
+        name="quadform_q8" if m_itemsize == 1 else "quadform",
     )(Zp, Mp, *rows, params)
 
     def heads_last(x):                                        # (kb, n, BK) -> (n, K)

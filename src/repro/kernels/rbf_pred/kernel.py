@@ -173,6 +173,7 @@ def rbf_predict_pallas(
             pltpu.SemaphoreType.DMA((2,)),
         ],
         interpret=interpret,
+        name="rbf_pred",
     )(jnp.reshape(jnp.asarray(gamma, jnp.float32), (1,)), Xp, sv_rows, Zp)
     scores = out[:n, :k] + jnp.reshape(jnp.asarray(b, jnp.float32), (1, -1))
     return scores if alpha_y.ndim == 2 else scores[:, 0]

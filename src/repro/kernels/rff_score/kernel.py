@@ -133,6 +133,7 @@ def rff_score_q8_pallas(
         out_specs=pl.BlockSpec((block_n, k_pad), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_pad, k_pad), jnp.float32),
         interpret=interpret,
+        name="rff_score_q8",
     )(Zp, Wp, wsp, pp, wtp, wtsp, bp)
     return out[:n, :k]
 
@@ -179,5 +180,6 @@ def rff_score_pallas(
         out_specs=pl.BlockSpec((block_n, k_pad), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_pad, k_pad), jnp.float32),
         interpret=interpret,
+        name="rff_score",
     )(Zp, Wp, pp, wtp, bp)
     return out[:n, :k]

@@ -7,8 +7,8 @@ Three layers, one bundle:
   conservation counters (see ``obs/README.md`` for the id contract).
 * ``metrics`` — typed counter/gauge/histogram registry with Prometheus
   text exposition (``render_prometheus()``).
-* ``profile`` — opt-in ``jax.profiler`` annotations around engine
-  steps and the backend dispatch seam.
+* ``profile`` — opt-in ``jax.profiler`` spans around each stage of a
+  flush, on the device trace's clock.
 
 ``Observability`` ties a ``Tracer`` to a ``MetricsRegistry``; every
 ``Runtime`` owns one (sharing the process default metrics registry

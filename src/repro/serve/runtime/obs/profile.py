@@ -1,21 +1,36 @@
-"""Opt-in kernel profiling hooks around the engine/backend seam.
+"""Opt-in host spans on the profiler's clock, around each stage of a flush.
 
-Two annotation layers, both off by default (zero steady-state cost —
-the hot path sees one module-global ``bool`` check):
+Off by default: the hot path sees one module-global check per stage and
+enters a shared ``nullcontext``. ``enable()`` turns every stage into a
+``jax.profiler.TraceAnnotation``, a host TraceMe event that lands in the
+``.xplane.pb`` on the same clock as the device's ``XLA Ops``, so a gap
+on the device can be read against what each host thread was doing.
 
-* **Host-side** — ``annotate(name)`` wraps the blocking dispatch of a
-  compiled engine step in ``jax.profiler.TraceAnnotation`` so the
-  profiler timeline shows which engine/bucket a device slice belongs
-  to. ``SVMEngine.submit`` / ``submit_exact`` call this around every
-  step.
-* **Trace-time** — ``enable()`` installs a ``jax.named_scope`` factory
-  into ``repro.core.backend`` (via ``backend.set_profile_scope``, a
-  callback hook so the core layer never imports serving code). Scoring
-  functions traced *while enabled* get their XLA ops grouped under
-  ``repro.backend/...`` scopes. Functions compiled before ``enable()``
-  keep their old op names until recompiled — enable first, then warm.
+The spans, in nesting order on the dispatching thread:
 
-``capture(path)`` bundles the whole flow: enable annotations, open a
+  runtime.flush                       one flush (metadata: the Tracer's
+                                      flush trace id, rows, bucket,
+                                      replica; ``degraded`` on the exact
+                                      path)
+    runtime.flush.assemble            concatenate the drained requests
+    svm_engine.pad/b{bkt}             zero buffer + copy into it
+    svm_engine.put/b{bkt}             host -> device issue
+    svm_engine.step/{family}/b{bkt}   the jitted call (the enqueue);
+                                      ``svm_engine.step_exact/b{bkt}``
+                                      on the degraded path
+    runtime.flush.resolve             breaker, futures, telemetry, spans
+
+and on whichever thread materializes a result first:
+
+  svm_engine.sync                     wait until the outputs are on the host
+  svm_engine.fallback                 exact re-score of rows outside Eq 3.11
+
+The scheduler enters its spans through ``annotate``; the engine, which
+never imports this package, gets the factory pushed into its own seam.
+Enable after warm-up: the spans are host events only and change no
+compiled program.
+
+``capture(path)`` bundles the whole flow: enable the spans, open a
 ``jax.profiler.trace`` session writing to ``path``, and restore the
 previous state on exit. ``Runtime.profile(model, Z, path)`` uses it to
 capture exactly one coalesced step.
@@ -26,10 +41,9 @@ from __future__ import annotations
 import contextlib
 import threading
 
-from repro.core import backend as _backend
-
 _lock = threading.Lock()
 _enabled = False
+_NO_SPAN = contextlib.nullcontext()
 
 
 def enabled() -> bool:
@@ -38,12 +52,8 @@ def enabled() -> bool:
 
 
 def enable(on: bool = True) -> bool:
-    """Toggle profiling annotations; returns the previous state.
-
-    Enabling installs a ``jax.named_scope`` factory into the backend
-    dispatch seam so newly traced scoring functions carry structured
-    op names; disabling uninstalls it.
-    """
+    """Toggle the stage spans (here and in the engine); returns the
+    previous state."""
     global _enabled
     with _lock:
         prev = _enabled
@@ -51,25 +61,23 @@ def enable(on: bool = True) -> bool:
         from repro.serve import svm_engine as _engine
 
         if _enabled:
-            import jax
             from jax.profiler import TraceAnnotation
 
-            _backend.set_profile_scope(jax.named_scope)
             _engine.set_profile_annotation(TraceAnnotation)
         else:
-            _backend.set_profile_scope(None)
             _engine.set_profile_annotation(None)
     return prev
 
 
-def annotate(name: str):
-    """Context manager: ``jax.profiler.TraceAnnotation`` when enabled,
-    a no-op otherwise. Safe to use on every hot-path step."""
+def annotate(name: str, **metadata):
+    """Context manager: ``jax.profiler.TraceAnnotation(name, **metadata)``
+    when enabled, a shared no-op otherwise. Safe on every hot-path
+    stage."""
     if not _enabled:
-        return contextlib.nullcontext()
+        return _NO_SPAN
     from jax.profiler import TraceAnnotation
 
-    return TraceAnnotation(name)
+    return TraceAnnotation(name, **metadata)
 
 
 @contextlib.contextmanager

@@ -145,10 +145,11 @@ class Tracer:
     def span_many(self, model: str, events: list) -> None:
         """Record many completed spans for one model in ONE enqueue.
 
-        The per-flush emission path: a coalesced flush produces one
-        ``engine.step``/``flush.dispatch`` span plus a queue-wait and a
-        verdict span per request — batching them amortizes the queue
-        put and the call overhead across the whole flush. Each event is
+        The per-flush emission path: a coalesced flush produces a
+        queue-wait and a verdict span per request — batching them
+        amortizes the queue put and the call overhead across the whole
+        flush (its ``engine.step`` span follows when the result reaches
+        the host). Each event is
         ``(name, trace_id, parent_id, t_start, t_end, attrs)``; span
         ids are minted here in event order (same (seed, ordinal)
         contract as ``span()``). Attrs dicts are taken by reference.

@@ -313,9 +313,9 @@ class Runtime:
         Warms ``model`` first so the capture shows steady-state serving
         (step dispatch + device compute), not compilation; then submits
         ``Z`` and materializes the result inside the profiler session,
-        with engine-step trace annotations enabled for the duration.
-        The trace directory is written to ``path`` (viewable with
-        TensorBoard's profile plugin). Returns ``path``.
+        with the flush-stage spans (``obs.profile``) enabled for the
+        duration. The trace directory is written to ``path`` (viewable
+        with TensorBoard's profile plugin). Returns ``path``.
         """
         self.warmup(model)
         with obs_profile.capture(path):

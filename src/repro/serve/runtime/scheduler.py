@@ -101,6 +101,7 @@ from repro.serve.runtime.errors import (
     RuntimeOverloaded,
 )
 from repro.serve.runtime.faults import ENGINE_STEP, FaultInjector
+from repro.serve.runtime.obs import profile as _profile
 from repro.serve.runtime.telemetry import ModelTelemetry
 
 DEFAULT_MAX_WAIT_US = 200.0
@@ -333,9 +334,9 @@ class MicroBatcher:
             if r.breaker is not None:
                 self.telemetry.record_breaker_state("closed", replica=r.index)
         # obs.Tracer (or None): every admitted request gets a trace id at
-        # submit; lifecycle spans (queue wait, dispatch, engine step,
-        # scatter, sync, verdicts) link to it. Span recording is a dict
-        # append under one lock — cheap enough for the hot path.
+        # submit; lifecycle spans (queue wait, engine step, verdicts)
+        # link to it. Span recording is an id mint and a queue put —
+        # cheap enough for the hot path.
         self._tracer = tracer
         self._cfg_strs: dict[int, str] = {}
         self._step_time_s = self.max_wait_s or 1e-4   # EWMA of measured steps
@@ -713,75 +714,90 @@ class MicroBatcher:
         bucket = replica.engine.bucket_for(
             min(rows, replica.engine.max_batch)
         )
-        def _emit_queue_waits():
-            # coalesce: each request's time in the queue, linked both to
-            # its own trace and (via attrs) to the flush that drained it.
-            # Emitted AFTER the engine step is dispatched: span bookkeeping
-            # for a deep coalesced batch then overlaps the asynchronous
-            # XLA work instead of sitting between the queue and the MXU.
-            if tr is not None:
+        with _profile.annotate("runtime.flush", trace=flush_trace, rows=rows,
+                               bucket=bucket, replica=replica.index):
+            try:
+                if self.faults is not None:
+                    if len(self.replicas) > 1:
+                        self.faults.check_replica(ENGINE_STEP, replica.index)
+                    else:
+                        self.faults.check(ENGINE_STEP)
+                with _profile.annotate("runtime.flush.assemble"):
+                    Z = np.concatenate([p.Z for p in batch], axis=0)
+                compiled_before = replica.engine.stats.compiled_steps
+                result = replica.engine.submit(Z)
+                recompiled = replica.engine.stats.compiled_steps > compiled_before
+                step_attrs = None
+                if tr is not None:
+                    cfg_str = self._cfg_strs.get(bucket)
+                    if recompiled or cfg_str is None:
+                        # dataclass repr is slow; cache per bucket, refresh on
+                        # recompile (the one event that can change the config)
+                        cfg_str = str(replica.engine.bucket_configs.get(bucket))
+                        self._cfg_strs[bucket] = cfg_str
+                    step_attrs = {"replica": replica.index, "bucket": bucket,
+                                  "tile_config": cfg_str, "recompiled": recompiled,
+                                  "rows": rows}
+                # e2e latency closes when the SHARED result first materializes
+                # (one sample per coalesced request, recorded by whichever
+                # client thread syncs first); per-row validity feeds the
+                # drift window the DriftGuard watches. engine.step spans the
+                # step from dispatch until its outputs are on the host.
+                enqueued = [p.t_enqueue for p in batch]
+                telemetry = self.telemetry
+
+                def _on_materialize(done, ts=enqueued, tel=telemetry, n=rows,
+                                    rep=replica, ftrace=flush_trace, t_step=t0,
+                                    step_attrs=step_attrs):
+                    t_done = time.perf_counter()
+                    for t_enq in ts:
+                        tel.record_latency(t_done - t_enq)
+                    valid = np.asarray(done[1])
+                    invalid = int(n - int(valid.sum()))
+                    tel.record_validity(n, invalid)
+                    self._span("engine.step", trace_id=ftrace,
+                               t_start=t_step, t_end=t_done, attrs=step_attrs)
+                    # fast-path ONLY: degraded flushes never emit a validity
+                    # span (mirrors record_validity's drift-window contract)
+                    self._span("flush.validity", trace_id=ftrace,
+                               t_end=t_done, attrs={"replica": rep.index,
+                                                    "rows": n,
+                                                    "invalid": invalid})
+                    with self._acct:
+                        rep.inflight_rows -= n
+
+                result.on_materialize = _on_materialize
+                slices = result.split(sizes)
+            except BaseException as e:                 # scatter the failure too
+                with self._acct:
+                    replica.inflight_rows -= rows
+                    replica.failures += 1
+                self.telemetry.record_flush(len(batch), rows, deadline=deadline,
+                                            tightened=tightened)
+                self.telemetry.record_batch_failure(len(batch), rows)
+                self.telemetry.record_replica_failure(replica.index)
+                # each request's time in the queue happened even if the step
+                # failed, linked both to its own trace and to the flush
                 for p in batch:
                     self._span("request.queue_wait", trace_id=p.trace,
                                t_start=p.t_enqueue, t_end=t0,
-                               attrs={"rows": p.Z.shape[0],
-                                      "flush": flush_trace})
+                               attrs={"rows": p.Z.shape[0], "flush": flush_trace})
+                self._span("flush.failed", trace_id=flush_trace, t_start=t0,
+                           attrs={"replica": replica.index, "rows": rows,
+                                  "error": type(e).__name__})
+                if replica.breaker is not None:
+                    replica.breaker.record_failure()
+                    self._sync_breaker_telemetry(replica)
+                self._fail_batch(batch, e, attrs={"replica": replica.index})
+                return
+            with _profile.annotate("runtime.flush.resolve"):
+                self._resolve(replica, batch, slices, rows, t0, flush_trace,
+                              bucket, deadline=deadline, tightened=tightened)
 
-        try:
-            if self.faults is not None:
-                if len(self.replicas) > 1:
-                    self.faults.check_replica(ENGINE_STEP, replica.index)
-                else:
-                    self.faults.check(ENGINE_STEP)
-            Z = np.concatenate([p.Z for p in batch], axis=0)
-            compiled_before = replica.engine.stats.compiled_steps
-            result = replica.engine.submit(Z)
-            recompiled = replica.engine.stats.compiled_steps > compiled_before
-            # e2e latency closes when the SHARED result first materializes
-            # (one sample per coalesced request, recorded by whichever
-            # client thread syncs first); per-row validity feeds the
-            # drift window the DriftGuard watches.
-            enqueued = [p.t_enqueue for p in batch]
-            telemetry = self.telemetry
-
-            def _on_materialize(done, ts=enqueued, tel=telemetry, n=rows,
-                                rep=replica, ftrace=flush_trace, t_sync=t0):
-                t_done = time.perf_counter()
-                for t_enq in ts:
-                    tel.record_latency(t_done - t_enq)
-                valid = np.asarray(done[1])
-                invalid = int(n - int(valid.sum()))
-                tel.record_validity(n, invalid)
-                self._span("flush.sync", trace_id=ftrace,
-                           t_start=t_sync, t_end=t_done,
-                           attrs={"replica": rep.index, "rows": n})
-                # fast-path ONLY: degraded flushes never emit a validity
-                # span (mirrors record_validity's drift-window contract)
-                self._span("flush.validity", trace_id=ftrace,
-                           t_end=t_done, attrs={"replica": rep.index,
-                                                "rows": n,
-                                                "invalid": invalid})
-                with self._acct:
-                    rep.inflight_rows -= n
-
-            result.on_materialize = _on_materialize
-            slices = result.split(sizes)
-        except BaseException as e:                 # scatter the failure too
-            with self._acct:
-                replica.inflight_rows -= rows
-                replica.failures += 1
-            self.telemetry.record_flush(len(batch), rows, deadline=deadline,
-                                        tightened=tightened)
-            self.telemetry.record_batch_failure(len(batch), rows)
-            self.telemetry.record_replica_failure(replica.index)
-            _emit_queue_waits()          # the wait happened even if the step failed
-            self._span("flush.failed", trace_id=flush_trace, t_start=t0,
-                       attrs={"replica": replica.index, "rows": rows,
-                              "error": type(e).__name__})
-            if replica.breaker is not None:
-                replica.breaker.record_failure()
-                self._sync_breaker_telemetry(replica)
-            self._fail_batch(batch, e, attrs={"replica": replica.index})
-            return
+    def _resolve(self, replica: _Replica, batch: list[_Pending], slices,
+                 rows: int, t0: float, flush_trace, bucket: int, *,
+                 deadline: bool, tightened: bool) -> None:
+        """After a successful enqueue: breaker, futures, telemetry, spans."""
         if replica.breaker is not None:
             replica.breaker.record_success()
             self._sync_breaker_telemetry(replica)
@@ -804,45 +820,25 @@ class MicroBatcher:
         self.telemetry.record_replica_flush(replica.index, len(batch), rows,
                                             bucket=bucket)
         self.telemetry.record_served(len(batch), rows)
+        tr = self._tracer
         if tr is not None:
-            cfg_str = self._cfg_strs.get(bucket)
-            if recompiled or cfg_str is None:
-                # dataclass repr is slow; cache per bucket, refresh on
-                # recompile (the one event that can change the config)
-                cfg_str = str(replica.engine.bucket_configs.get(bucket))
-                self._cfg_strs[bucket] = cfg_str
-            # one batched enqueue for the whole flush: step + dispatch
-            # plus per-request queue-wait (linked to the flush trace via
-            # attrs) and served verdicts — same spans and the same id
-            # order as per-call emission, a fraction of the hot-path cost
+            # one batched enqueue for the whole flush: per-request queue
+            # wait (linked to the flush trace via attrs) and served
+            # verdicts — the same spans and id order as per-call
+            # emission, a fraction of the hot-path cost
             now = tr.clock()
             ridx = replica.index
             events = [
-                ("engine.step", flush_trace, None, t0, now, {
-                    "replica": ridx,
-                    "bucket": bucket,
-                    "tile_config": cfg_str,
-                    "recompiled": recompiled,
-                    "rows": rows,
-                }),
+                ("request.queue_wait", p.trace, None, p.t_enqueue, t0,
+                 {"rows": p.Z.shape[0], "flush": flush_trace})
+                for p in batch
             ]
-            for p in batch:
-                events.append(
-                    ("request.queue_wait", p.trace, None, p.t_enqueue, t0,
-                     {"rows": p.Z.shape[0], "flush": flush_trace})
-                )
-            events.append(
-                ("flush.dispatch", flush_trace, None, t0, now,
-                 {"replica": ridx, "requests": len(batch), "rows": rows,
-                  "bucket": bucket, "deadline": deadline,
-                  "tightened": tightened})
-            )
-            for p in batch:
-                events.append(
-                    ("request.served", p.trace, None, p.t_enqueue, now,
-                     {"rows": p.Z.shape[0], "replica": ridx,
-                      "flush": flush_trace})
-                )
+            events += [
+                ("request.served", p.trace, None, p.t_enqueue, now,
+                 {"rows": p.Z.shape[0], "replica": ridx,
+                  "flush": flush_trace})
+                for p in batch
+            ]
             tr.span_many(self.name, events)
 
     def _execute_degraded(self, batch: list[_Pending], sizes, rows: int, *,
@@ -857,60 +853,62 @@ class MicroBatcher:
         t0 = time.perf_counter()
         tr = self._tracer
         flush_trace = tr.new_trace() if tr is not None else None
-        if not getattr(self.engine, "exact_available", False):
-            # soonest probe window across replicas: the honest retry hint
-            retry = min((r.breaker.retry_after() for r in self.replicas
-                         if r.breaker is not None), default=0.0)
+        with _profile.annotate("runtime.flush", trace=flush_trace, rows=rows,
+                               degraded=True):
+            if not getattr(self.engine, "exact_available", False):
+                # soonest probe window across replicas: the honest retry hint
+                retry = min((r.breaker.retry_after() for r in self.replicas
+                             if r.breaker is not None), default=0.0)
+                self.telemetry.record_flush(len(batch), rows, deadline=deadline,
+                                            tightened=tightened)
+                self.telemetry.record_breaker_shed(len(batch))
+                self._fail_batch(batch, RuntimeOverloaded(
+                    f"model {self.name!r}: circuit breaker open and no exact "
+                    f"model published to degrade to",
+                    retry_after_s=retry or self.max_wait_s,
+                ), attrs={"reason": "breaker_shed"})
+                return
+            try:
+                Z = np.concatenate([p.Z for p in batch], axis=0)
+                result = self.engine.submit_exact(Z)
+                enqueued = [p.t_enqueue for p in batch]
+                telemetry = self.telemetry
+
+                # latency only — degraded rows are exact-served and must NOT
+                # feed the drift window (a fault is not input drift); for the
+                # same reason no flush.validity span is emitted here
+                def _on_materialize(done, ts=enqueued, tel=telemetry,
+                                    ftrace=flush_trace, n=rows, t_step=t0):
+                    t_done = time.perf_counter()
+                    for t_enq in ts:
+                        tel.record_latency(t_done - t_enq)
+                    self._span("engine.step", trace_id=ftrace,
+                               t_start=t_step, t_end=t_done,
+                               attrs={"rows": n, "degraded": True})
+
+                result.on_materialize = _on_materialize
+                slices = result.split(sizes)
+            except BaseException as e:
+                self.telemetry.record_flush(len(batch), rows, deadline=deadline,
+                                            tightened=tightened)
+                self.telemetry.record_batch_failure(len(batch), rows)
+                self._span("flush.failed", trace_id=flush_trace, t_start=t0,
+                           attrs={"rows": rows, "degraded": True,
+                                  "error": type(e).__name__})
+                self._fail_batch(batch, e, attrs={"degraded": True})
+                return
             self.telemetry.record_flush(len(batch), rows, deadline=deadline,
                                         tightened=tightened)
-            self.telemetry.record_breaker_shed(len(batch))
-            self._fail_batch(batch, RuntimeOverloaded(
-                f"model {self.name!r}: circuit breaker open and no exact "
-                f"model published to degrade to",
-                retry_after_s=retry or self.max_wait_s,
-            ), attrs={"reason": "breaker_shed"})
-            return
-        try:
-            Z = np.concatenate([p.Z for p in batch], axis=0)
-            result = self.engine.submit_exact(Z)
-            enqueued = [p.t_enqueue for p in batch]
-            telemetry = self.telemetry
-
-            # latency only — degraded rows are exact-served and must NOT
-            # feed the drift window (a fault is not input drift); for the
-            # same reason no flush.validity span is emitted here
-            def _on_materialize(done, ts=enqueued, tel=telemetry,
-                                ftrace=flush_trace, n=rows, t_sync=t0):
-                t_done = time.perf_counter()
-                for t_enq in ts:
-                    tel.record_latency(t_done - t_enq)
-                self._span("flush.sync", trace_id=ftrace,
-                           t_start=t_sync, t_end=t_done,
-                           attrs={"rows": n, "degraded": True})
-
-            result.on_materialize = _on_materialize
-            slices = result.split(sizes)
-        except BaseException as e:
-            self.telemetry.record_flush(len(batch), rows, deadline=deadline,
-                                        tightened=tightened)
-            self.telemetry.record_batch_failure(len(batch), rows)
-            self._span("flush.failed", trace_id=flush_trace, t_start=t0,
-                       attrs={"rows": rows, "degraded": True,
-                              "error": type(e).__name__})
-            self._fail_batch(batch, e, attrs={"degraded": True})
-            return
-        self.telemetry.record_flush(len(batch), rows, deadline=deadline,
-                                    tightened=tightened)
-        self.telemetry.record_degraded(len(batch), rows)
-        self.telemetry.record_served(len(batch), rows)
-        self._span("flush.degraded", trace_id=flush_trace, t_start=t0,
-                   attrs={"requests": len(batch), "rows": rows})
-        for p, s in zip(batch, slices):
-            self._span("request.served", trace_id=p.trace,
-                       t_start=p.t_enqueue, attrs={
-                           "rows": p.Z.shape[0],
-                           "degraded": True,
-                           "flush": flush_trace,
-                       })
-            if p.future.set_running_or_notify_cancel():
-                p.future.set_result(s)
+            self.telemetry.record_degraded(len(batch), rows)
+            self.telemetry.record_served(len(batch), rows)
+            self._span("flush.degraded", trace_id=flush_trace, t_start=t0,
+                       attrs={"requests": len(batch), "rows": rows})
+            for p, s in zip(batch, slices):
+                self._span("request.served", trace_id=p.trace,
+                           t_start=p.t_enqueue, attrs={
+                               "rows": p.Z.shape[0],
+                               "degraded": True,
+                               "flush": flush_trace,
+                           })
+                if p.future.set_running_or_notify_cancel():
+                    p.future.set_result(s)

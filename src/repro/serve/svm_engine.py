@@ -78,6 +78,7 @@ bucket histogram, compile count).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import threading
 
@@ -95,27 +96,26 @@ from repro.kernels.common import TileConfig, tuning
 Array = jax.Array
 
 # Profiling seam: repro.serve.runtime.obs.profile installs a context-
-# manager factory (jax.profiler.TraceAnnotation) here so engine steps
-# show up as named host-side slices in profiler timelines. Push-pattern
-# like backend.set_profile_scope — the engine never imports obs, and the
-# disabled hot path costs one module-global None check per step.
+# manager factory (jax.profiler.TraceAnnotation) here so each stage of a
+# step (pad, put, step, sync, fallback) shows up as a named host span on
+# the device trace's clock. The engine never imports obs, and the
+# disabled hot path costs one module-global None check per stage.
 _profile_annotation = None
+_NO_SPAN = contextlib.nullcontext()
+_NO_NAMES = (None, None, None)
 
 
 def set_profile_annotation(factory) -> None:
     """Install (or clear, with None) a ``name -> context manager`` factory
-    wrapped around every engine step dispatch."""
+    wrapped around each stage of every engine step."""
     global _profile_annotation
     _profile_annotation = factory
 
 
-def _annotate(name: str):
+def _annotate(name: str | None):
+    # a span starts when its TraceAnnotation is made: make it at the with
     factory = _profile_annotation
-    if factory is None:
-        import contextlib
-
-        return contextlib.nullcontext()
-    return factory(name)
+    return _NO_SPAN if factory is None or name is None else factory(name)
 
 
 def bucket_size(n: int, min_bucket: int = 32, max_batch: int = 8192) -> int:
@@ -348,6 +348,7 @@ class SVMEngine:
         self.bucket_configs: dict[int, TileConfig] = {}
         self.stats = EngineStats()
         self._trace_lock = threading.Lock()   # guards bucket_configs
+        self._span_names: dict[tuple[int, bool], tuple[str, str, str]] = {}
         self._device = device                 # replica pinning (scale-out)
         self.head_mesh = head_mesh
 
@@ -445,23 +446,46 @@ class SVMEngine:
             return jax.device_put(buf, self._device)
         return jnp.asarray(buf)
 
-    def submit(self, Z) -> EngineResult:
-        """Enqueue one batch; returns without waiting for device compute."""
+    def _stage_names(self, bkt: int, exact: bool):
+        """(pad, put, step) span names for one chunk; Nones unless
+        profiling is on. Built once per bucket."""
+        if _profile_annotation is None:
+            return _NO_NAMES
+        names = self._span_names.get((bkt, exact))
+        if names is None:
+            step = (f"svm_engine.step_exact/b{bkt}" if exact
+                    else f"svm_engine.step/{self.family}/b{bkt}")
+            names = (f"svm_engine.pad/b{bkt}", f"svm_engine.put/b{bkt}", step)
+            self._span_names[(bkt, exact)] = names
+        return names
+
+    def _enqueue(self, Z, step, *, exact: bool):
+        """Pad each ``max_batch`` chunk of ``Z`` to its bucket, copy it to
+        the device and enqueue ``step`` on it; returns (Z, chunks)."""
         Z = np.asarray(Z, dtype=np.float32)
         if Z.ndim != 2 or Z.shape[1] != self.d:
             raise ValueError(f"expected (n, {self.d}) batch, got {Z.shape}")
-        n = Z.shape[0]
         chunks = []
-        for start in range(0, max(n, 1), self.max_batch):
+        for start in range(0, max(Z.shape[0], 1), self.max_batch):
             rows = Z[start : start + self.max_batch]
             m = rows.shape[0]
             bkt = bucket_size(m, self.min_bucket, self.max_batch)
-            buf = np.zeros((bkt, self.d), dtype=np.float32)
-            buf[:m] = rows                                  # host-side pad
-            with _annotate(f"svm_engine.step/{self.family}/b{bkt}"):
-                out = self._step(self._put(buf))
+            pad, put, run = self._stage_names(bkt, exact)
+            with _annotate(pad):
+                buf = np.zeros((bkt, self.d), dtype=np.float32)
+                buf[:m] = rows                              # host-side pad
+            with _annotate(put):
+                x = self._put(buf)
+            with _annotate(run):
+                out = step(x)
             chunks.append((out, m))
-        self.stats.record_batch(n, [(c[0][0].shape[0], c[1]) for c in chunks])
+        return Z, chunks
+
+    def submit(self, Z) -> EngineResult:
+        """Enqueue one batch; returns without waiting for device compute."""
+        Z, chunks = self._enqueue(Z, self._step, exact=False)
+        self.stats.record_batch(Z.shape[0],
+                                [(c[0][0].shape[0], c[1]) for c in chunks])
         # Z is only needed to re-score bound-violating rows; don't pin the
         # host copy of every deferred batch when no fallback can happen.
         return EngineResult(self, Z if self.allow_fallback else None, chunks)
@@ -484,21 +508,8 @@ class SVMEngine:
         """
         if self._slow_step is None:
             raise RuntimeError("submit_exact needs an exact model (none given)")
-        Z = np.asarray(Z, dtype=np.float32)
-        if Z.ndim != 2 or Z.shape[1] != self.d:
-            raise ValueError(f"expected (n, {self.d}) batch, got {Z.shape}")
-        n = Z.shape[0]
-        chunks = []
-        for start in range(0, max(n, 1), self.max_batch):
-            rows = Z[start : start + self.max_batch]
-            m = rows.shape[0]
-            bkt = bucket_size(m, self.min_bucket, self.max_batch)
-            buf = np.zeros((bkt, self.d), dtype=np.float32)
-            buf[:m] = rows
-            with _annotate(f"svm_engine.step_exact/b{bkt}"):
-                out = self._slow_step(self._put(buf))
-            chunks.append((out, m))
-        self.stats.record_degraded(n)
+        Z, chunks = self._enqueue(Z, self._slow_step, exact=True)
+        self.stats.record_degraded(Z.shape[0])
         return EngineResult(self, None, chunks)   # exact already: no re-score
 
     def predict(self, Z) -> tuple[np.ndarray, np.ndarray]:
@@ -605,23 +616,25 @@ class SVMEngine:
     def _finalize(self, Z: np.ndarray | None, chunks):
         """One host sync per result: concat chunks, slice padding, patch
         bound-violating rows through the exact path."""
-        scores = np.concatenate(
-            [np.asarray(out[0])[:m] for out, m in chunks]
-        ) if chunks else np.zeros((0, self.num_heads), np.float32)
+        with _annotate("svm_engine.sync"):            # device wait + D2H
+            scores = np.concatenate(
+                [np.asarray(out[0])[:m] for out, m in chunks]
+            ) if chunks else np.zeros((0, self.num_heads), np.float32)
+            valid = np.concatenate([np.asarray(out[1])[:m] for out, m in chunks]) \
+                if chunks else np.zeros((0,), bool)
+            labels = np.concatenate([np.asarray(out[2])[:m] for out, m in chunks]) \
+                if chunks else np.zeros((0,), np.int32)
         if scores.shape[1] != self.num_heads:
             # head-sharded serving pads K up to the mesh axis size; the
             # padding heads are argmax-neutral, so labels are already
             # correct — only the score columns need slicing back down.
             scores = np.ascontiguousarray(scores[:, : self.num_heads])
-        valid = np.concatenate([np.asarray(out[1])[:m] for out, m in chunks]) \
-            if chunks else np.zeros((0,), bool)
-        labels = np.concatenate([np.asarray(out[2])[:m] for out, m in chunks]) \
-            if chunks else np.zeros((0,), np.int32)
 
         if Z is not None and self.allow_fallback and not valid.all():
             idx = np.nonzero(~valid)[0]
             self.stats.record_fallback(len(idx))
-            exact_scores = np.asarray(self._slow(self._put(Z[idx])))  # (m, K)
+            with _annotate("svm_engine.fallback"):
+                exact_scores = np.asarray(self._slow(self._put(Z[idx])))  # (m, K)
             scores[idx] = exact_scores
             if self.multiclass:
                 labels[idx] = exact_scores.argmax(axis=-1)

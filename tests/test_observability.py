@@ -216,7 +216,7 @@ def test_runtime_exposes_first_class_gauges_and_spans():
         rt.predict("m", _rows(rng, 2))
         futs = [rt.submit("m", _rows(rng, 3)) for _ in range(8)]
         for f in futs:
-            f.result(timeout=30.0)
+            f.result(timeout=30.0).values       # engine.step closes on the host
 
         text = rt.render_prometheus()
         samples = _parse_prometheus(text)
@@ -237,6 +237,7 @@ def test_runtime_exposes_first_class_gauges_and_spans():
         steps = rt.obs.tracer.spans(key, "engine.step")
         assert steps, "engine steps must be traced"
         for s in steps:
+            assert s["t_end"] > s["t_start"]
             assert s["attrs"]["bucket"] in (8, 16, 32, 64)
             assert "TileConfig" in s["attrs"]["tile_config"]
             assert s["attrs"]["recompiled"] in (True, False)
@@ -483,21 +484,132 @@ def test_runtime_profile_writes_a_trace(tmp_path):
 
 
 def test_profile_hooks_install_and_uninstall_cleanly():
+    import contextlib
+
+    from jax.profiler import TraceAnnotation
+
     from repro.serve import svm_engine
     from repro.serve.runtime.obs import profile as obs_profile
-    import repro.core.backend as backend
 
     assert not obs_profile.enabled()
-    assert backend._profile_scope is None
     assert svm_engine._profile_annotation is None
+    assert isinstance(obs_profile.annotate("runtime.flush"), contextlib.nullcontext)
     prev = obs_profile.enable(True)
     try:
         assert prev is False and obs_profile.enabled()
-        assert backend._profile_scope is not None
         assert svm_engine._profile_annotation is not None
-        with obs_profile.annotate("test/annotation"):
+        span = obs_profile.annotate("test/annotation", rows=4)
+        assert isinstance(span, TraceAnnotation)
+        with span:
             pass
     finally:
         obs_profile.enable(False)
-    assert backend._profile_scope is None
     assert svm_engine._profile_annotation is None
+    assert isinstance(obs_profile.annotate("runtime.flush"), contextlib.nullcontext)
+
+
+def _host_events(trace_dir):
+    """Host events of the one ``.xplane.pb`` under ``trace_dir``:
+    {thread line: [(start_ns, end_ns, name, metadata)]}."""
+    import jax
+
+    import warnings
+
+    (path,) = list(trace_dir.rglob("*.xplane.pb"))
+    lines = {}
+    with warnings.catch_warnings():     # jaxlib's stats type warns when read
+        warnings.simplefilter("ignore", DeprecationWarning)
+        for plane in jax.profiler.ProfileData.from_file(str(path)).planes:
+            if plane.name.startswith("/host:"):
+                for i, line in enumerate(plane.lines):
+                    lines[(plane.name, i, line.name)] = [
+                        (e.start_ns, e.start_ns + e.duration_ns, e.name,
+                         dict(e.stats))
+                        for e in line.events
+                    ]
+    return lines
+
+
+FLUSH_STAGES = (
+    "runtime.flush.assemble",
+    "svm_engine.pad/b8",
+    "svm_engine.put/b8",
+    "svm_engine.step/maclaurin/b8",
+    "runtime.flush.resolve",
+)
+
+
+def test_flush_stage_spans_reach_the_profiler_trace(tmp_path):
+    import jax
+
+    from repro.serve.runtime.obs import profile as obs_profile
+
+    m = _svm(0)
+    rng = np.random.default_rng(0)
+    with Runtime(engine_opts=ENGINE_OPTS, obs=Observability(seed=5)) as rt:
+        digest = rt.publish("m", maclaurin.compile(m), PublishSpec(exact=m))
+        rt.predict("m", _rows(rng, 4))                  # warm the path
+        obs_profile.enable(True)
+        try:
+            with jax.profiler.trace(str(tmp_path / "on")):
+                rt.submit("m", _rows(rng, 4)).result(timeout=30.0).values
+        finally:
+            obs_profile.enable(False)
+        with jax.profiler.trace(str(tmp_path / "off")):
+            rt.submit("m", _rows(rng, 4)).result(timeout=30.0).values
+        step = rt.obs.tracer.spans(digest[:12], "engine.step")[-2]
+
+    on = _host_events(tmp_path / "on")
+    with_flush = [evs for evs in on.values()
+                  if any(e[2] == "runtime.flush" for e in evs)]
+    assert len(with_flush) == 1, "one flush, on one thread line"
+    events = with_flush[0]
+    (flush,) = [e for e in events if e[2] == "runtime.flush"]
+    f0, f1, _, meta = flush
+    assert meta == {"trace": step["trace_id"], "rows": 4, "bucket": 8,
+                    "replica": 0}
+    stages = sorted((e for e in events if e[2] in FLUSH_STAGES),
+                    key=lambda e: e[0])
+    assert [e[2] for e in stages] == list(FLUSH_STAGES)
+    assert all(f0 <= s and e <= f1 for s, e, _, _ in stages), "nested"
+    assert all(a[1] <= b[0] for a, b in zip(stages, stages[1:])), "in order"
+    syncs = [e for evs in on.values() for e in evs if e[2] == "svm_engine.sync"]
+    assert len(syncs) == 1 and syncs[0][0] >= stages[3][0]
+
+    off = {e[2] for evs in _host_events(tmp_path / "off").values() for e in evs}
+    assert not off & {"runtime.flush", "svm_engine.sync", *FLUSH_STAGES}
+
+
+def test_degraded_flush_records_its_spans(tmp_path):
+    """Every breaker open: the flush goes down the exact path and records
+    ``runtime.flush`` (degraded) around pad, put and ``step_exact``."""
+    import jax
+
+    from repro.serve.runtime.obs import profile as obs_profile
+
+    m = _svm(0)
+    rng = np.random.default_rng(0)
+    faults = FaultInjector(seed=0)
+    with Runtime(engine_opts=ENGINE_OPTS, obs=Observability(),
+                 fault_injector=faults,
+                 breaker=dict(fail_threshold=1, reset_after_s=60.0)) as rt:
+        rt.publish("m", maclaurin.compile(m), PublishSpec(exact=m))
+        faults.fail_next(ENGINE_STEP, 1)
+        with pytest.raises(InjectedFault):
+            rt.predict("m", _rows(rng, 4))                  # trips the breaker
+        obs_profile.enable(True)
+        try:
+            with jax.profiler.trace(str(tmp_path)):
+                res = rt.submit("m", _rows(rng, 4)).result(timeout=30.0)
+                assert not res.valid.any()                  # exact-served
+        finally:
+            obs_profile.enable(False)
+
+    events = [e for evs in _host_events(tmp_path).values() for e in evs]
+    (flush,) = [e for e in events if e[2] == "runtime.flush"]
+    assert flush[3]["degraded"] and flush[3]["rows"] == 4
+    names = [e[2] for e in sorted(events, key=lambda e: e[0])
+             if flush[0] <= e[0] and e[1] <= flush[1]]
+    stages = [n for n in names if n.startswith("svm_engine.")]
+    assert stages == ["svm_engine.pad/b8", "svm_engine.put/b8",
+                      "svm_engine.step_exact/b8"]
