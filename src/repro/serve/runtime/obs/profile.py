@@ -13,7 +13,8 @@ The spans, in nesting order on the dispatching thread:
                                       replica; ``degraded`` on the exact
                                       path)
     runtime.flush.assemble            concatenate the drained requests
-    svm_engine.pad/b{bkt}             zero buffer + copy into it
+    svm_engine.pad/b{bkt}             staging copy into the bucket buffer;
+                                      padding rows zeroed
     svm_engine.put/b{bkt}             host -> device issue
     svm_engine.step/{family}/b{bkt}   the jitted call (the enqueue);
                                       ``svm_engine.step_exact/b{bkt}``

@@ -370,7 +370,9 @@ class MicroBatcher:
         if the request cannot be flushed within that many seconds of
         submission.
         """
-        Z = np.asarray(Z, dtype=np.float32)
+        # a copy (in the rows' own memory order): the request waits in the
+        # queue after this returns, and the caller may reuse its array
+        Z = np.array(Z, dtype=np.float32)
         if Z.ndim == 1:
             Z = Z[None, :]
         if Z.ndim != 2 or Z.shape[1] != self.engine.d:

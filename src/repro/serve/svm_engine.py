@@ -208,10 +208,11 @@ class EngineResult:
     then the exact fallback for rows outside the Eq 3.11 envelope).
     """
 
-    def __init__(self, engine: "SVMEngine", Z: np.ndarray | None, chunks):
+    def __init__(self, engine: "SVMEngine", staged: list | None, chunks):
         self._engine = engine
-        self._Z = Z                      # original rows (fallback re-scores);
-                                         # None when no fallback can happen
+        self._staged = staged            # the engine's copy of each chunk's
+                                         # rows (fallback re-scores); None
+                                         # when no fallback can happen
         self._chunks = chunks            # [(scores, valid, labels), n_rows]
         self._done = None
         self._sync = threading.Lock()    # scatter consumers race to be first
@@ -228,7 +229,7 @@ class EngineResult:
         # mutates fallback counters — double-running would double-count).
         with self._sync:
             if self._done is None:
-                self._done = self._engine._finalize(self._Z, self._chunks)
+                self._done = self._engine._finalize(self._staged, self._chunks)
                 if self.on_materialize is not None:
                     # the hook receives the finalized (values, valid,
                     # labels) so the scheduler can record per-row validity
@@ -460,35 +461,47 @@ class SVMEngine:
         return names
 
     def _enqueue(self, Z, step, *, exact: bool):
-        """Pad each ``max_batch`` chunk of ``Z`` to its bucket, copy it to
-        the device and enqueue ``step`` on it; returns (Z, chunks)."""
+        """Copy each ``max_batch`` chunk of ``Z`` into a staging buffer of
+        its bucket's size (padding rows zeroed), copy that to the device
+        and enqueue ``step`` on it; returns (staged rows, chunks).
+
+        The staging buffer keeps the rows' memory order, so the copy is a
+        straight one. A column-major batch (what ``np.asarray`` of an
+        array on the TPU gives) staged row-major would cost a transpose on
+        the host, while the transfer lays the rows out for the device
+        either way. The staging copy is also what lets a caller reuse its
+        array as soon as ``submit`` returns: the fallback re-scores from
+        it, never from the caller's array."""
         Z = np.asarray(Z, dtype=np.float32)
         if Z.ndim != 2 or Z.shape[1] != self.d:
             raise ValueError(f"expected (n, {self.d}) batch, got {Z.shape}")
-        chunks = []
+        staged, chunks = [], []
         for start in range(0, max(Z.shape[0], 1), self.max_batch):
             rows = Z[start : start + self.max_batch]
             m = rows.shape[0]
             bkt = bucket_size(m, self.min_bucket, self.max_batch)
             pad, put, run = self._stage_names(bkt, exact)
             with _annotate(pad):
-                buf = np.zeros((bkt, self.d), dtype=np.float32)
-                buf[:m] = rows                              # host-side pad
+                buf = np.empty_like(rows, shape=(bkt, self.d))
+                buf[:m] = rows
+                buf[m:] = 0
             with _annotate(put):
                 x = self._put(buf)
             with _annotate(run):
                 out = step(x)
+            staged.append(buf[:m])
             chunks.append((out, m))
-        return Z, chunks
+        return staged, chunks
 
     def submit(self, Z) -> EngineResult:
         """Enqueue one batch; returns without waiting for device compute."""
-        Z, chunks = self._enqueue(Z, self._step, exact=False)
-        self.stats.record_batch(Z.shape[0],
+        staged, chunks = self._enqueue(Z, self._step, exact=False)
+        self.stats.record_batch(sum(m for _, m in chunks),
                                 [(c[0][0].shape[0], c[1]) for c in chunks])
-        # Z is only needed to re-score bound-violating rows; don't pin the
-        # host copy of every deferred batch when no fallback can happen.
-        return EngineResult(self, Z if self.allow_fallback else None, chunks)
+        # the staged rows are only needed to re-score bound-violating rows;
+        # don't pin them for every deferred batch when no fallback can happen.
+        return EngineResult(self, staged if self.allow_fallback else None,
+                            chunks)
 
     @property
     def exact_available(self) -> bool:
@@ -508,8 +521,8 @@ class SVMEngine:
         """
         if self._slow_step is None:
             raise RuntimeError("submit_exact needs an exact model (none given)")
-        Z, chunks = self._enqueue(Z, self._slow_step, exact=True)
-        self.stats.record_degraded(Z.shape[0])
+        _, chunks = self._enqueue(Z, self._slow_step, exact=True)
+        self.stats.record_degraded(sum(m for _, m in chunks))
         return EngineResult(self, None, chunks)   # exact already: no re-score
 
     def predict(self, Z) -> tuple[np.ndarray, np.ndarray]:
@@ -613,7 +626,7 @@ class SVMEngine:
 
     # ----------------------------------------------------------- materialize
 
-    def _finalize(self, Z: np.ndarray | None, chunks):
+    def _finalize(self, staged: list | None, chunks):
         """One host sync per result: concat chunks, slice padding, patch
         bound-violating rows through the exact path."""
         with _annotate("svm_engine.sync"):            # device wait + D2H
@@ -630,8 +643,9 @@ class SVMEngine:
             # correct — only the score columns need slicing back down.
             scores = np.ascontiguousarray(scores[:, : self.num_heads])
 
-        if Z is not None and self.allow_fallback and not valid.all():
+        if staged is not None and self.allow_fallback and not valid.all():
             idx = np.nonzero(~valid)[0]
+            Z = staged[0] if len(staged) == 1 else np.concatenate(staged)
             self.stats.record_fallback(len(idx))
             with _annotate("svm_engine.fallback"):
                 exact_scores = np.asarray(self._slow(self._put(Z[idx])))  # (m, K)

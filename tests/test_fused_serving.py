@@ -1,6 +1,8 @@
 """Fused multi-head serving path: kernel vs per-head oracle, backend
 dispatch, engine shape-bucketing (zero recompiles within a bucket),
-deferred sync, and the mesh-sharded exact fallback."""
+deferred sync, the mesh-sharded exact fallback, and the staging copy
+(padding rows zeroed, the rows' memory order kept, the caller's array
+free once submit returns)."""
 
 import numpy as np
 import jax
@@ -13,7 +15,9 @@ from repro.kernels.common import TileConfig
 from repro.data.synthetic import make_blobs
 from repro.kernels.quadform.kernel import quadform_heads_pallas
 from repro.kernels.quadform.ref import quadform_heads_ref
-from repro.serve.svm_engine import SVMEngine, bucket_size
+from repro.core.families import maclaurin
+from repro.serve import PublishSpec, Runtime, svm_engine
+from repro.serve.svm_engine import EngineResult, SVMEngine, bucket_size
 from repro.svm import train_lssvm
 from repro.svm.multiclass import (
     approx_ovr_predict,
@@ -189,3 +193,111 @@ def test_engine_multiclass_fused_argmax():
     Zbad = 50.0 * X[:3]
     bad_labels = eng.predict_labels(Zbad)
     np.testing.assert_array_equal(bad_labels, np.asarray(ovr_predict(m, Zbad)))
+
+
+# ------------------------------------------------------ the staging copy
+
+
+class _NanStaging:
+    """numpy, except that ``empty_like`` hands back NaNs, as a reused
+    allocation may: a padding row left unzeroed then shows."""
+
+    def __getattr__(self, name):
+        return getattr(np, name)
+
+    @staticmethod
+    def empty_like(*args, **kw):
+        out = np.empty_like(*args, **kw)
+        out.fill(np.nan)
+        return out
+
+
+def _served_rows(X, m, order):
+    """``m`` rows cycled from ``X``, every fifth outside the Eq 3.11
+    envelope (so the exact fallback runs), in memory order ``order``."""
+    Z = np.asarray(X)[np.arange(m) % X.shape[0]].copy()
+    Z[::5] *= 50.0
+    return np.asarray(Z, np.float32, order=order)
+
+
+def _zero_padded_reference(eng, Z):
+    """``eng``'s result for ``Z`` with each chunk staged in a row-major
+    ``np.zeros`` buffer of its bucket's size."""
+    Z = np.ascontiguousarray(Z, np.float32)
+    staged, chunks = [], []
+    for start in range(0, Z.shape[0], eng.max_batch):
+        rows = Z[start:start + eng.max_batch]
+        bkt = bucket_size(len(rows), eng.min_bucket, eng.max_batch)
+        buf = np.zeros((bkt, eng.d), np.float32)
+        buf[:len(rows)] = rows
+        staged.append(rows)
+        chunks.append((eng._step(eng._put(buf)), len(rows)))
+    return EngineResult(eng, staged, chunks)
+
+
+@pytest.fixture(scope="module")
+def staging_engine():
+    eng, _, X = _binary_engine(min_bucket=32, max_batch=128)
+    return eng, X
+
+
+# 1, min_bucket - 1, min_bucket, a bucket - 1, a full bucket, max_batch + 3
+@pytest.mark.parametrize("order", ["C", "F"])
+@pytest.mark.parametrize("m", [1, 31, 32, 63, 64, 131])
+def test_engine_stages_rows_with_zeroed_padding(staging_engine, monkeypatch,
+                                                m, order):
+    """What ``_put`` gets is each chunk's rows, then bit-exact zeros to
+    the bucket, in the rows' memory order; the served scores, verdicts
+    and labels equal a zero-padded row-major staging, bit for bit."""
+    eng, X = staging_engine
+    Z = _served_rows(X, m, order)
+    handed = []
+    put = eng._put
+    monkeypatch.setattr(eng, "_put", lambda buf: handed.append(buf) or put(buf))
+    monkeypatch.setattr(svm_engine, "np", _NanStaging())
+    r = eng.submit(Z)
+    monkeypatch.undo()                     # the fallback's puts come later
+    starts = range(0, m, eng.max_batch)
+    assert len(handed) == len(starts)
+    for buf, start in zip(handed, starts):
+        rows = Z[start:start + eng.max_batch]
+        k = len(rows)
+        assert buf.shape == (bucket_size(k, 32, 128), eng.d)
+        assert buf.dtype == np.float32
+        np.testing.assert_array_equal(buf[:k], rows)
+        assert not buf[k:].view(np.uint32).any()          # +0.0, every bit
+        if order == "F" and k > 1:
+            assert buf.flags.f_contiguous
+        else:
+            assert buf.flags.c_contiguous
+    want = _zero_padded_reference(eng, Z)
+    assert not want.valid.all()
+    np.testing.assert_array_equal(r.values, want.values)
+    np.testing.assert_array_equal(r.valid, want.valid)
+    np.testing.assert_array_equal(r.labels, want.labels)
+
+
+@pytest.mark.parametrize("entry", ["engine", "runtime"])
+def test_caller_may_overwrite_its_rows_once_submit_returns(entry):
+    """Rows overwritten right after ``submit`` returns are still scored,
+    and fallback rows re-scored, as they were when submitted."""
+    eng, m, X = _binary_engine(min_bucket=32, max_batch=128)
+    Z = _served_rows(X, 40, "C")
+    if entry == "engine":
+        want = eng.predict(Z.copy())
+        r = eng.submit(Z)
+        Z[:] = 7.0
+        got = r.values, r.valid
+    else:
+        # the request waits in the queue for max_wait_us after submit
+        with Runtime(max_wait_us=50_000,
+                     engine_opts=dict(min_bucket=32, max_batch=128)) as rt:
+            rt.publish("m", maclaurin.compile(m), PublishSpec(exact=m))
+            want = rt.predict("m", Z.copy())
+            fut = rt.submit("m", Z)
+            Z[:] = 7.0
+            res = fut.result(timeout=60)
+            got = res.values, res.valid
+    assert not want[1].all()
+    np.testing.assert_array_equal(got[0], want[0])
+    np.testing.assert_array_equal(got[1], want[1])
