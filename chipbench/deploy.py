@@ -2,9 +2,18 @@
 
 The benchmark draws the support vectors, the coefficients and the rows it
 sends from ``--seed`` itself, on the device, in one jitted call each, as
-the configuration's feature kind prescribes (copied from the shapes of
-the paper's Table 1 data sets). The program is handed only the exact
-model and the rows; ``reference.py`` computes from the same drawn arrays.
+the configuration's feature kind prescribes. The kinds follow the feature
+character of the paper's Table 1 data sets (the configuration's
+``features``):
+
+  binary        a9a: d = 123, one-hot codes of the 14 attributes of UCI
+                Adult, one 1 in each attribute's group of columns
+  pixels        mnist: about 19% of entries uniform in [0, 1], the rest 0
+  dense         ijcnn1, sensit: uniform in [-0.8, 0.8]
+  standardized  epsilon: standard normal rows scaled to unit L2 norm
+
+The program is handed only the exact model and the rows; ``reference.py``
+computes from the same drawn arrays.
 
     gamma = gamma_ratio x gamma_max,  gamma_max = 1 / (4 max_i ||x_i||^2)
 
@@ -42,11 +51,33 @@ def key_for(seed: int, stream: int):
     return jax.random.fold_in(key, stream)
 
 
+# a9a's columns, attribute by attribute (the LIBSVM binary data page: the
+# 6 continuous attributes of UCI Adult cut into quantiles, the 8
+# categorical ones one column per category): age 5, workclass 8, fnlwgt 5,
+# education 16, education-num 5, marital-status 7, occupation 14,
+# relationship 6, race 5, sex 2, capital-gain 2, capital-loss 2,
+# hours-per-week 5, native-country 41. Every drawn row has 14 ones, so
+# ||x||^2 = 14 as in a9a's rows with no missing attribute.
+A9A_GROUPS = (5, 8, 5, 16, 5, 7, 14, 6, 5, 2, 2, 2, 5, 41)
+_A9A_GROUP = np.repeat(np.arange(len(A9A_GROUPS)), A9A_GROUPS)
+_A9A_CATEGORY = np.concatenate([np.arange(g) for g in A9A_GROUPS])
+
+
 def _features(key, n: int, d: int, kind: str):
     k1, k2 = jax.random.split(key)
-    if kind == "pixels":            # mnist-like: [0, 1] values, about 81% zeros
+    if kind == "binary":
+        if d != len(_A9A_GROUP):
+            raise ValueError(f"binary rows are a9a's {len(_A9A_GROUP)} columns, not d={d}")
+        pick = jax.random.randint(k1, (n, len(A9A_GROUPS)), 0, np.asarray(A9A_GROUPS))
+        return (pick[:, _A9A_GROUP] == _A9A_CATEGORY).astype(jnp.float32)
+    if kind == "pixels":
         keep = jax.random.uniform(k1, (n, d)) < 0.19
         return jnp.where(keep, jax.random.uniform(k2, (n, d)), 0.0)
+    if kind == "dense":
+        return jax.random.uniform(k1, (n, d), minval=-0.8, maxval=0.8)
+    if kind == "standardized":
+        x = jax.random.normal(k1, (n, d))
+        return x / jnp.linalg.norm(x, axis=1, keepdims=True)
     raise ValueError(f"unknown feature kind {kind!r}")
 
 

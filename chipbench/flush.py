@@ -35,11 +35,9 @@ idle 98% of the window); a backlog would add its wait to the reading.
 A trace with no ``runtime.flush`` span (a program that records none)
 reads as nothing: every reader returns None.
 
-The harness hands a reader the reduced trace (``trace.Summary``), which
-keeps no host events and not the file. ``of(run)`` therefore reads the
-file itself, from the ``trace_dir`` of the harness call that is running
-(the nearest caller with such a local), once per run, and raises if a
-traced run has none.
+The reduced trace (``trace.Summary``) keeps no host events, so
+``of(run)`` reads them from the file the harness hands every reader,
+``run.trace_path``, once per run, and raises if a traced run has none.
 """
 
 from __future__ import annotations
@@ -47,8 +45,6 @@ from __future__ import annotations
 import bisect
 import collections
 import dataclasses
-import os
-import sys
 
 from chipbench import trace
 
@@ -144,11 +140,9 @@ def _stage_of(name: str) -> str | None:
 
 
 def load(path: str, summary) -> Flushes | None:
-    """The flush spans of the trace at ``path`` (a file or a trace
-    directory); ``summary`` is the harness's reduction of the same trace,
-    whose device operations give each chip's busy intervals."""
-    if os.path.isdir(path):
-        path = trace.find_xplane(path)
+    """The flush spans of the ``.xplane.pb`` at ``path``; ``summary`` is
+    the harness's reduction of the same trace, whose device operations
+    give each chip's busy intervals."""
     window, lines, programs = _events(path, summary.devices)
     if window is None:
         return None
@@ -198,16 +192,6 @@ def load(path: str, summary) -> Flushes | None:
     )
 
 
-def _trace_dir():
-    frame = sys._getframe(1)
-    while frame is not None:
-        found = frame.f_locals.get("trace_dir")
-        if isinstance(found, (str, os.PathLike)) and os.path.exists(found):
-            return os.fspath(found)
-        frame = frame.f_back
-    return None
-
-
 def of(run) -> Flushes | None:
     """The flush spans of this run's trace, read once and kept on ``run``;
     None without a trace or without flush spans. A traced run whose trace
@@ -216,10 +200,9 @@ def of(run) -> Flushes | None:
     if "flush_spans" not in cache:
         found = None
         if run.trace is not None:
-            trace_dir = _trace_dir()
-            if trace_dir is None:
-                raise RuntimeError("a traced run, but no caller holds its trace_dir: "
+            if run.trace_path is None:
+                raise RuntimeError("a traced run without its trace path: "
                                    "the flush readers cannot find the .xplane.pb")
-            found = load(trace_dir, run.trace)
+            found = load(run.trace_path, run.trace)
         cache["flush_spans"] = found
     return cache["flush_spans"]

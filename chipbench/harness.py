@@ -69,10 +69,14 @@ class Window:
     """Opens and closes the measured window: program counters before and
     after, a ``chipbench.window`` span, and with tracing the profiler with
     the program's own annotations on (only after warm-up, so no compiled
-    program changes)."""
+    program changes).
 
-    def __init__(self, runtime, alias: str, trace_dir: str | None):
+    The counters are the runtime's for the cell's alias, and
+    ``fallback_rows``: rows the engines re-scored by the exact path."""
+
+    def __init__(self, runtime, alias: str, engines: list, trace_dir: str | None):
         self.runtime, self.alias, self.trace_dir = runtime, alias, trace_dir
+        self.engines = engines
         self.compiles = 0
         self._open = False
         self._span = None
@@ -86,8 +90,10 @@ class Window:
 
     def counters(self) -> dict:
         st = self.runtime.stats(self.alias)
-        return {k: st[k] for k in ("rows", "flushes", "served_rows", "served_requests",
-                                   "failed_requests", "batch_failures")}
+        out = {k: st[k] for k in ("rows", "flushes", "served_rows", "served_requests",
+                                  "failed_requests", "batch_failures")}
+        out["fallback_rows"] = sum(e.stats.fallback_instances for e in self.engines)
+        return out
 
     def open(self) -> None:
         import jax
@@ -129,12 +135,16 @@ class Window:
 
 
 class Run:
-    """What a per-layer metric's ``read(run)`` sees."""
+    """What a per-layer metric's ``read(run)`` sees: the cell's
+    configuration and chips, the reduced trace (``trace.Summary``) and the
+    path of the ``.xplane.pb`` it was reduced from, the window's counters
+    (``Window``) and the device's peaks."""
 
-    def __init__(self, cell, trace, counters, peaks):
+    def __init__(self, cell, trace, counters, peaks, trace_path: str | None = None):
         self.config = cell.config
         self.chips = cell.chips
         self.trace = trace
+        self.trace_path = trace_path
         self.counters = counters
         self.peaks = peaks
         self.notes: list = []
@@ -157,10 +167,10 @@ def end_to_end(cell, driven: drive.Driven, setup_s: float) -> dict:
     return out
 
 
-def per_layer(cell, run: Run) -> dict:
+def per_layer(cell, run: Run, root: str = spec.ROOT) -> dict:
     out = {}
     for m in cell.per_layer:
-        value = spec.load_metric(m["name"]).read(run)
+        value = spec.load_metric(m["name"], root).read(run)
         if value is not None:
             out[m["name"]] = {"value": float(value), "unit": m["unit"]}
     return out
@@ -246,30 +256,34 @@ def execute(args, t_start: float, *, root: str = spec.ROOT, chips_check=require_
     model, pool, runtime, engines = prepare(cell, args.seed)
     alias = cell.config_name
     compiled_warm = sum(e.stats.compiled_steps for e in engines)
-    window = Window(runtime, alias, trace_dir)
+    window = Window(runtime, alias, engines, trace_dir)
     driven = drive.bulk(runtime, alias, pool, cell.traffic, cell.chips, args.seed,
                         args.seconds, window)
     setup_s = window.t_open - t_start
     stats = runtime.stats(alias)
     recompiles = sum(e.stats.compiled_steps for e in engines) - compiled_warm
-    fallback = sum(e.stats.fallback_instances for e in engines)
     peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0) for d in devices)
     runtime.close()
+    # the window's compile listener stays registered for the process:
+    # drop its references so the program's state can be freed
+    window.runtime = window.engines = None
     del engines, runtime
     gc.collect()
 
     notes = [f"setup_s {setup_s!r}", *driven.notes, window.host_note,
              f"window: {window.compiles} compile events, {recompiles} engine recompiles, "
-             f"rows {window.delta['rows']} flushes {window.delta['flushes']}",
+             f"rows {window.delta['rows']} flushes {window.delta['flushes']} "
+             f"fallback_rows {window.delta['fallback_rows']}",
              f"account: batch_failures={stats['batch_failures']} "
              f"failed_requests={stats['failed_requests']} breaker={stats['breaker']['state']} "
-             f"degraded_rows={stats['breaker']['degraded_rows']} fallback_rows={fallback}",
+             f"degraded_rows={stats['breaker']['degraded_rows']}",
              f"memory_peak_bytes {peak}"]
-    trace = None
+    trace = xplane = None
     if trace_dir is not None:
         from chipbench import trace as trace_mod
 
-        trace = trace_mod.summarize(trace_dir, [d.id for d in devices])
+        xplane = trace_mod.find_xplane(trace_dir)
+        trace = trace_mod.summarize(xplane, [d.id for d in devices])
     t_ref = time.perf_counter()
     numbers, ctl, compared = judge(cell, model, pool, driven, control=bool(args.control))
     notes.append(f"reference: {compared} served rows compared in "
@@ -291,8 +305,8 @@ def execute(args, t_start: float, *, root: str = spec.ROOT, chips_check=require_
         result["metrics"] = end_to_end(cell, driven, setup_s)
     else:
         peaks = load_peaks(dev0.device_kind, root)
-        run = Run(cell, trace, window.delta, peaks)
-        result["metrics"] = per_layer(cell, run)
+        run = Run(cell, trace, window.delta, peaks, trace_path=xplane)
+        result["metrics"] = per_layer(cell, run, root)
         notes += run.notes
         result["device"]["busy_s"] = trace.mean_busy_s()
         result["device"]["window_s"] = trace.window_s

@@ -23,15 +23,14 @@ NEW_READERS = ("flush_host_ms.bulk", "flush_prep_ms.bulk", "h2d_ms.bulk",
                "result_sync_ms.bulk", "idle_under_flush.bulk")
 
 
-def _run(summary, rows):
+def _run(summary, rows, trace_path=None):
     cell = spec.Cell("mnist-bulk", "mnist-ovr10", "bulk-closed", 1, CONFIG, {}, {}, [], [])
     counters = {"served_rows": rows, "rows": rows, "flushes": rows // 8192}
-    return harness.Run(cell, summary, counters, harness.load_peaks("TPU v5 lite"))
+    return harness.Run(cell, summary, counters, harness.load_peaks("TPU v5 lite"),
+                       trace_path=trace_path)
 
 
-def _read(name, run, trace_dir):
-    """A reader called as the harness calls it: under a call whose
-    ``trace_dir`` is the run's trace."""
+def _read(name, run):
     return spec.load_metric(name).read(run)
 
 
@@ -77,20 +76,22 @@ def test_breakdown_reads_its_pinned_entries(old):
 
 @pytest.mark.parametrize("name", NEW_READERS)
 def test_flush_readers_read_nothing_from_a_trace_without_spans(old, name):
-    assert _read(name, _run(old, 2 * 8192), OLD) is None
+    assert _read(name, _run(old, 2 * 8192, OLD)) is None
 
 
 @pytest.mark.parametrize("name", NEW_READERS)
 def test_flush_readers_read_nothing_without_a_trace(name):
     run = harness.Run(spec.Cell("c", "x", "y", 1, CONFIG, {}, {}, [], []), None,
-                      {"served_rows": 0, "rows": 0, "flushes": 0}, {})
-    assert _read(name, run, OLD) is None
+                      {"served_rows": 0, "rows": 0, "flushes": 0}, {}, trace_path=OLD)
+    assert _read(name, run) is None
 
 
 @pytest.mark.parametrize("name", NEW_READERS)
 def test_flush_readers_raise_when_a_traced_run_has_no_trace_dir(old, name):
-    with pytest.raises(RuntimeError, match="trace_dir"):
-        spec.load_metric(name).read(_run(old, 2 * 8192))
+    """A traced ``Run`` without the path of its trace raises: the metrics
+    must not vanish unseen."""
+    with pytest.raises(RuntimeError, match="without its trace path"):
+        _read(name, _run(old, 2 * 8192))
 
 
 # ------------------------------------------------ the spans, by hand
@@ -131,8 +132,37 @@ def new():
 
 @pytest.mark.parametrize("name", NEW_READERS)
 def test_flush_readers_read_the_values_worked_out_by_hand(new, name):
-    run = _run(new, 3 * 8192)
-    assert _read(name, run, NEW) == pytest.approx(NEW_VALUES[name], rel=1e-12)
+    run = _run(new, 3 * 8192, NEW)
+    assert _read(name, run) == pytest.approx(NEW_VALUES[name], rel=1e-12)
+
+
+# Every reader's value on both committed traces, as the readers read them
+# while they found the trace by its caller's ``trace_dir`` (recorded
+# before the harness handed them ``Run.trace_path``).
+PINNED = {
+    (OLD, "device_idle.bulk"): 96.57234668904171,
+    (OLD, "quadform_roofline.bulk"): 69.75833758902588,
+    (OLD, "step_mfu.bulk"): 2.158847848005415,
+    (OLD, "flush_host_ms.bulk"): None,
+    (OLD, "flush_prep_ms.bulk"): None,
+    (OLD, "h2d_ms.bulk"): None,
+    (OLD, "result_sync_ms.bulk"): None,
+    (OLD, "idle_under_flush.bulk"): None,
+    (NEW, "device_idle.bulk"): 98.67866004465907,
+    (NEW, "quadform_roofline.bulk"): 69.77213770156501,
+    (NEW, "step_mfu.bulk"): 0.8327634043127329,
+    (NEW, "flush_host_ms.bulk"): 56.421892666666665,
+    (NEW, "flush_prep_ms.bulk"): 54.91962266666666,
+    (NEW, "h2d_ms.bulk"): 9.146339000000001,
+    (NEW, "result_sync_ms.bulk"): 13.254513333333334,
+    (NEW, "idle_under_flush.bulk"): 93.21024476613823,
+}
+
+
+@pytest.mark.parametrize("path,name", sorted(PINNED), ids=lambda v: os.path.basename(v))
+def test_every_reader_reads_its_pinned_value_from_the_trace_path(old, new, path, name):
+    summary, rows = (old, 2 * 8192) if path == OLD else (new, 3 * 8192)
+    assert _read(name, _run(summary, rows, path)) == PINNED[(path, name)]
 
 
 def test_flush_stages_cover_the_flush(new):

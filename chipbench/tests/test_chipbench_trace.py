@@ -66,3 +66,16 @@ def test_work_counts_follow_shapes_not_tiles():
     t, bound = work.roofline_seconds(work.quadform_flops(8192, 10, 780), one, 197e12, 819e9)
     assert bound == "compute" and t == pytest.approx(8192 * 10 * (2 * 780**2 + 4 * 780) / 197e12)
     assert work.roofline_seconds(1.0, 1e9, 197e12, 819e9)[1] == "memory"
+
+
+@pytest.mark.parametrize("rows,calls,heads,n_sv,d,flops,nbytes", [
+    # epsilon: per row 2*36,988*2,000 + 2*2,000 + 2*36,988 = 148,029,976;
+    # one call reads (73,976,000 + 36,988) floats, each row 2,001
+    (8192, 1, 1, 36988, 2000, 1_212_661_563_392, 296_051_952 + 65_568_768),
+    # mnist-ovr10: per row 3,391,440 + 1,560 + 43,480 = 3,436,480; two
+    # calls of (1,695,720 + 21,740) floats, each row 790
+    (8192, 2, 10, 2174, 780, 28_151_644_160, 13_739_680 + 25_886_720),
+])
+def test_exact_work_counts_by_hand(rows, calls, heads, n_sv, d, flops, nbytes):
+    assert work.exact_flops(rows, heads, n_sv, d) == flops
+    assert work.exact_bytes(rows, calls, heads, n_sv, d) == nbytes

@@ -106,12 +106,10 @@ def _module_of(modules: list, starts: np.ndarray, t: float) -> str:
 
 
 def summarize(path: str, devices: list) -> Summary:
-    """Reduce the trace at ``path`` (a file or a trace directory) for the
-    devices the cell uses (indices)."""
+    """Reduce the ``.xplane.pb`` at ``path`` for the devices the cell uses
+    (indices)."""
     import jax
 
-    if os.path.isdir(path):
-        path = find_xplane(path)
     pd = jax.profiler.ProfileData.from_file(path)
     window = None
     host = []                                   # (start, end, name)

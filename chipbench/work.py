@@ -26,6 +26,23 @@ def quadform_bytes(rows: int, calls: int, heads: int, d: int) -> float:
     return float(calls) * per_call + float(rows) * per_row
 
 
+def exact_flops(rows: int, heads: int, n_sv: int, d: int) -> float:
+    """The exact RBF expansion for ``rows`` rows and ``heads`` heads: per
+    row, the cross term z . x_i for every support vector (2 n_sv d), the
+    row's squared norm (2 d) and the K-head readout sum_i alpha_ki k_i
+    (2 K n_sv). The support vectors' norms are the model's, computed once."""
+    return float(rows) * (2.0 * n_sv * d + 2.0 * d + 2.0 * heads * n_sv)
+
+
+def exact_bytes(rows: int, calls: int, heads: int, n_sv: int, d: int) -> float:
+    """Least HBM traffic of the exact expansion: each call reads the
+    (n_sv, d) support vectors and the (K, n_sv) coefficients once; every
+    row is read once (d floats) and writes its K scores."""
+    per_call = (n_sv * d + heads * n_sv) * F32
+    per_row = (d + heads) * F32
+    return float(calls) * per_call + float(rows) * per_row
+
+
 def step_flops(rows: int, heads: int, d: int) -> float:
     """Operations per served row of the pinned maclaurin family, the
     quadratic term 2 K d^2 of the step that dominates it."""
