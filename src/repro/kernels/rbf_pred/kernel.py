@@ -35,8 +35,11 @@ the f32 the exact path promises.
 
 VMEM working set per step (f32): 2*BN*d (Z tile) + 2*BM*d (X slots) +
 2*(KB+8)*BM (SV-row slots) + BN*BM (kernel tile) + 3*BN*KB (accumulator
-and output) — with BN=BM=256, d<=2048 and K<=16: ~9 MB, inside the
-16 MiB scoped default of a v5e core.
+and output) — with BN=BM=256, d<=2048 and K<=16: ~9 MB. The loaded z and
+x tiles and their bf16 halves at HIGHEST about double it: compiled for a
+v5e, the kernel needs 7 MiB of scoped VMEM at d_pad 896, 19 MiB at 2,048
+and 37 MiB at 4,096. ``_vmem_limit_bytes`` asks for that, never less than
+the 16 MiB scoped default.
 
 Block sizes come from ``repro.kernels.common`` (``TileConfig.block_n`` /
 ``block_m``), resolved per shape bucket by the tuning registry.
@@ -111,6 +114,16 @@ def _kernel(g_ref, x_hbm, sv_hbm, z_ref, o_ref, x_slots, sv_slots, sem_x, sem_sv
     )
 
 
+_DEFAULT_VMEM = 16 << 20
+
+
+def _vmem_limit_bytes(block_n: int, block_m: int, d_pad: int) -> int:
+    """Scoped VMEM for one grid step: about 4.5 f32 copies of the
+    (block_n + block_m, d_pad) rows (the double-buffered Z block and X
+    slots, the loaded tiles, their bf16 halves) and 2 MiB for the rest."""
+    return max(_DEFAULT_VMEM, 18 * (block_n + block_m) * d_pad + (2 << 20))
+
+
 def rbf_predict_pallas(
     Z: jax.Array,
     X: jax.Array,
@@ -173,6 +186,9 @@ def rbf_predict_pallas(
             pltpu.SemaphoreType.DMA((2,)),
         ],
         interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_limit_bytes(block_n, block_m, d_pad)
+        ),
         name="rbf_pred",
     )(jnp.reshape(jnp.asarray(gamma, jnp.float32), (1,)), Xp, sv_rows, Zp)
     scores = out[:n, :k] + jnp.reshape(jnp.asarray(b, jnp.float32), (1, -1))

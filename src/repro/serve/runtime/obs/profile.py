@@ -25,6 +25,11 @@ and on whichever thread materializes a result first:
 
   svm_engine.sync                     wait until the outputs are on the host
   svm_engine.fallback                 exact re-score of rows outside Eq 3.11
+    svm_engine.fallback.gather/b{bkt} the rows gathered into a staging
+                                      buffer of their bucket's size
+    svm_engine.fallback.put/b{bkt}    host -> device issue
+    svm_engine.fallback.step/b{bkt}   the exact program's enqueue
+    svm_engine.fallback.sync          device wait and copy back
 
 The scheduler enters its spans through ``annotate``; the engine, which
 never imports this package, gets the factory pushed into its own seam.

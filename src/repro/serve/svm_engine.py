@@ -66,9 +66,14 @@ Exact fallback (bounded-accuracy serving)
   rows are re-scored with the exact expansion via the streaming
   ``rbf_pred`` path (Pallas kernel on TPU: SV tiles streamed
   flash-attention style, never materializing the (n, n_sv) kernel
-  matrix). With a ``mesh``, the support vectors are sharded across
-  devices (shard_map + psum over the first mesh axis) so arbitrarily
-  large exact models serve the slow path too. The paper recommends
+  matrix). The exact model reaches that program as arguments, never as
+  constants: one small program per bucket serves every engine of the
+  same shapes and fits the persistent compile cache. The invalid rows
+  are gathered into a staging buffer of their bucket's size, so any
+  count of them compiles at most one exact program per bucket. With a
+  ``mesh``, the support vectors are sharded across devices (shard_map +
+  psum over the first mesh axis) so arbitrarily large exact models serve
+  the slow path too. The paper recommends
   adhering to the bound; the fallback is our beyond-paper extension for
   inputs outside the verified envelope.
 
@@ -80,12 +85,13 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import threading
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import backend, families
 from repro.core.families import CompiledArtifact
@@ -128,6 +134,64 @@ def bucket_size(n: int, min_bucket: int = 32, max_batch: int = 8192) -> int:
     return tuning.bucket(n, lo=min_bucket, hi=max_batch)
 
 
+def _labels(scores, multiclass: bool):
+    return (jnp.argmax(scores, axis=-1) if multiclass
+            else jnp.where(scores[:, 0] >= 0, 1, -1))
+
+
+@functools.lru_cache(maxsize=None)
+def _exact_programs(mesh: Mesh | None):
+    """The exact expansion through the streaming ``rbf_pred`` path, as two
+    jitted functions of the rows and the model:
+
+      exact_scores(Zb, X, alpha_y, gamma, b)  -> (m, K) scores
+      exact_step(Zp, X, alpha_y, gamma, b)    -> (scores, valid, labels),
+                                                 valid all False, shaped
+                                                 like the engine's step
+
+    ``alpha_y`` is (K, n_sv): every head shares ONE pass over the SVs.
+    The support vectors, coefficients, gamma and bias are arguments, never
+    constants of the executable, so the program is small, the persistent
+    compile cache holds it, and every engine of the same shapes shares one
+    compiled program per bucket. The static ``backend_name`` keys the
+    programs on the backend their trace reads. With a ``mesh`` the SVs
+    arrive sharded over its first axis and the partial sums are psum'd.
+    """
+    if mesh is None:
+        def scores(Zb, X, alpha_y, gamma, b):
+            return backend.rbf_scores(Zb, X, alpha_y, gamma, b)
+    else:
+        axis = mesh.axis_names[0]
+
+        def _partial(Zb, Xs, ays, gamma):
+            f = backend.rbf_scores(Zb, Xs, ays, gamma, 0.0)   # (m, K)
+            return jax.lax.psum(f, axis)                      # replicated
+
+        # check_vma=False: the Pallas rbf_pred call carries no vma type
+        sharded = jax.shard_map(
+            _partial,
+            mesh=mesh,
+            in_specs=(P(), P(axis, None), P(None, axis), P()),
+            out_specs=P(),
+            check_vma=False,
+        )
+
+        def scores(Zb, X, alpha_y, gamma, b):
+            return sharded(Zb, X, alpha_y, gamma) + jnp.reshape(b, (1, -1))
+
+    def exact_scores(Zb, X, alpha_y, gamma, b, *, backend_name):
+        del backend_name                          # a cache key only
+        return scores(Zb, X, alpha_y, gamma, b)
+
+    def exact_step(Zp, X, alpha_y, gamma, b, *, backend_name, multiclass):
+        del backend_name
+        out = scores(Zp, X, alpha_y, gamma, b)
+        return out, jnp.zeros((Zp.shape[0],), bool), _labels(out, multiclass)
+
+    return (jax.jit(exact_scores, static_argnames=("backend_name",)),
+            jax.jit(exact_step, static_argnames=("backend_name", "multiclass")))
+
+
 @dataclasses.dataclass
 class EngineStats:
     """Serving counters, safe under concurrent ``submit()`` callers.
@@ -142,6 +206,7 @@ class EngineStats:
     batches: int = 0
     instances: int = 0
     fallback_instances: int = 0
+    fallback_batches: int = 0           # exact-path calls of the fallback
     compiled_steps: int = 0             # bucket variants traced (compile count)
     padded_instances: int = 0           # wasted rows from bucket padding
     degraded_batches: int = 0           # submit_exact batches (breaker open)
@@ -160,9 +225,11 @@ class EngineStats:
                 self.padded_instances += bkt - m
                 self.bucket_hits[bkt] = self.bucket_hits.get(bkt, 0) + 1
 
-    def record_fallback(self, k: int) -> None:
+    def record_fallback(self, k: int, calls: int) -> None:
+        """One result's fallback: k rows re-scored in ``calls`` exact calls."""
         with self._lock:
             self.fallback_instances += k
+            self.fallback_batches += calls
 
     def record_degraded(self, n: int) -> None:
         """One ``submit_exact`` batch of n rows (kept OUT of the fast-path
@@ -184,6 +251,7 @@ class EngineStats:
                 "instances": self.instances,
                 "fallback_instances": self.fallback_instances,
                 "fallback_rate": self.fallback_instances / max(1, self.instances),
+                "fallback_batches": self.fallback_batches,
                 "compiled_steps": self.compiled_steps,
                 "padded_instances": self.padded_instances,
                 "padding_overhead": self.padded_instances / max(1, self.instances),
@@ -349,7 +417,7 @@ class SVMEngine:
         self.bucket_configs: dict[int, TileConfig] = {}
         self.stats = EngineStats()
         self._trace_lock = threading.Lock()   # guards bucket_configs
-        self._span_names: dict[tuple[int, bool], tuple[str, str, str]] = {}
+        self._span_names: dict[tuple[int, str], tuple[str, str, str]] = {}
         self._device = device                 # replica pinning (scale-out)
         self.head_mesh = head_mesh
 
@@ -383,34 +451,17 @@ class SVMEngine:
                 )
             else:
                 scores, valid_row = self._family.score(artifact, Zp, config=cfg)
-            if self.multiclass:
-                labels = jnp.argmax(scores, axis=-1)       # fused argmax
-            else:
-                labels = jnp.where(scores[:, 0] >= 0, 1, -1)
-            return scores, valid_row, labels
+            return scores, valid_row, _labels(scores, self.multiclass)  # fused
 
         self._step = jax.jit(_step)
-        self._slow = self._build_slow(exact, mesh) if exact is not None else None
 
-        # Degraded-mode step (circuit breaker open): the exact expansion
-        # through the streaming rbf_pred path, shaped like _step so the
-        # coalesced scatter machinery works unchanged. valid is all-False
-        # — the rows were served OUTSIDE the approximation contract's
-        # fast path, same semantics as fallback-patched rows.
-        if self._slow is not None:
-            slow = self._slow
-
-            def _slow_full(Zp):
-                scores = slow(Zp)                               # (m, K)
-                if self.multiclass:
-                    labels = jnp.argmax(scores, axis=-1)
-                else:
-                    labels = jnp.where(scores[:, 0] >= 0, 1, -1)
-                return scores, jnp.zeros((Zp.shape[0],), bool), labels
-
-            self._slow_step = jax.jit(_slow_full)
-        else:
-            self._slow_step = None
+        # The exact model (fallback and degraded rows): device arrays
+        # handed to the shared exact programs on every call, never baked
+        # into an executable (``_exact_programs``).
+        self._backend = backend.resolve()
+        self._exact_scores, self._exact_step = _exact_programs(mesh)
+        self._exact_weights = (self._exact_arrays(exact, mesh)
+                               if exact is not None else None)
 
     # ---------------------------------------------------------- tile tuning
 
@@ -447,17 +498,23 @@ class SVMEngine:
             return jax.device_put(buf, self._device)
         return jnp.asarray(buf)
 
-    def _stage_names(self, bkt: int, exact: bool):
-        """(pad, put, step) span names for one chunk; Nones unless
-        profiling is on. Built once per bucket."""
+    def _stage_names(self, bkt: int, kind: str):
+        """Span names of one chunk's three stages, built once per bucket;
+        Nones unless profiling is on. ``kind`` is "step" (pad, put, the
+        fast step), "step_exact" (pad, put, the degraded step) or
+        "fallback" (gather, put, the exact call)."""
         if _profile_annotation is None:
             return _NO_NAMES
-        names = self._span_names.get((bkt, exact))
+        names = self._span_names.get((bkt, kind))
         if names is None:
-            step = (f"svm_engine.step_exact/b{bkt}" if exact
-                    else f"svm_engine.step/{self.family}/b{bkt}")
-            names = (f"svm_engine.pad/b{bkt}", f"svm_engine.put/b{bkt}", step)
-            self._span_names[(bkt, exact)] = names
+            if kind == "fallback":
+                names = tuple(f"svm_engine.fallback.{stage}/b{bkt}"
+                              for stage in ("gather", "put", "step"))
+            else:
+                step = (f"svm_engine.step_exact/b{bkt}" if kind == "step_exact"
+                        else f"svm_engine.step/{self.family}/b{bkt}")
+                names = (f"svm_engine.pad/b{bkt}", f"svm_engine.put/b{bkt}", step)
+            self._span_names[(bkt, kind)] = names
         return names
 
     def _enqueue(self, Z, step, *, exact: bool):
@@ -480,7 +537,7 @@ class SVMEngine:
             rows = Z[start : start + self.max_batch]
             m = rows.shape[0]
             bkt = bucket_size(m, self.min_bucket, self.max_batch)
-            pad, put, run = self._stage_names(bkt, exact)
+            pad, put, run = self._stage_names(bkt, "step_exact" if exact else "step")
             with _annotate(pad):
                 buf = np.empty_like(rows, shape=(bkt, self.d))
                 buf[:m] = rows
@@ -506,7 +563,7 @@ class SVMEngine:
     @property
     def exact_available(self) -> bool:
         """True when an exact model was published (``submit_exact`` works)."""
-        return self._slow_step is not None
+        return self._exact_weights is not None
 
     def submit_exact(self, Z) -> EngineResult:
         """Score ``Z`` entirely through the exact streaming ``rbf_pred``
@@ -519,7 +576,7 @@ class SVMEngine:
         keeps the bounded-compile property (one slow variant per bucket,
         not per batch shape). Requires an exact model.
         """
-        if self._slow_step is None:
+        if self._exact_weights is None:
             raise RuntimeError("submit_exact needs an exact model (none given)")
         _, chunks = self._enqueue(Z, self._slow_step, exact=True)
         self.stats.record_degraded(sum(m for _, m in chunks))
@@ -573,58 +630,69 @@ class SVMEngine:
 
     # ------------------------------------------------------------- slow path
 
-    def _build_slow(self, exact: SVMModel, mesh: Mesh | None):
-        """Exact re-scorer through the streaming rbf_pred backend path.
-
-        Every head shares ONE pass over the SVs (alpha_y as (K, n_sv)).
-        Without a mesh the SVs live on this engine's device, so a
-        replica's fallback and degraded rows are scored where its fast
-        path runs. With a mesh, SVs are sharded over its first axis (rows
-        padded with alpha = 0, which contribute exactly 0) and partial
-        sums psum'd.
-        """
+    def _exact_arrays(self, exact: SVMModel, mesh: Mesh | None) -> tuple:
+        """(X, alpha_y as (K, n_sv), gamma, b as (K,)) on the device(s) the
+        exact path runs on. Without a mesh they live on this engine's
+        device, so a replica's fallback and degraded rows are scored where
+        its fast path runs. With a mesh the SVs are sharded over its first
+        axis, padded with alpha = 0 rows, which contribute exactly 0."""
         ay = np.asarray(exact.alpha_y, np.float32)
-        ay2 = ay[None, :] if ay.ndim == 1 else ay           # (K, n_sv)
-        X = np.asarray(exact.X, np.float32)
-        gamma, bias = exact.gamma, exact.b
-
+        ay = ay[None, :] if ay.ndim == 1 else ay
+        arrays = (np.asarray(exact.X, np.float32), ay,
+                  np.asarray(exact.gamma, np.float32),
+                  np.asarray(exact.b, np.float32).reshape(-1))
         if mesh is None:
-            Xd, ayd = self._put(X), self._put(ay2)
-
-            @jax.jit
-            def slow(Zb):
-                return backend.rbf_scores(Zb, Xd, ayd, gamma, bias)  # (m, K)
-
-            return slow
-
+            return tuple(self._put(a) for a in arrays)
+        X, ay, gamma, b = arrays
         axis = mesh.axis_names[0]
-        shards = mesh.shape[axis]
-        pad = (-X.shape[0]) % shards
-        Xp = np.pad(X, ((0, pad), (0, 0)))
-        ayp = np.pad(ay2, ((0, 0), (0, pad)))               # alpha 0 => 0 contribution
-        Xd = jax.device_put(Xp)
-        ayd = jax.device_put(ayp)
+        pad = (-X.shape[0]) % mesh.shape[axis]
+        arrays = (np.pad(X, ((0, pad), (0, 0))), np.pad(ay, ((0, 0), (0, pad))), gamma, b)
+        specs = (P(axis, None), P(None, axis), P(), P())
+        return tuple(jax.device_put(a, NamedSharding(mesh, spec))
+                     for a, spec in zip(arrays, specs))
 
-        def _partial(Zb, Xs, ays):
-            f = backend.rbf_scores(Zb, Xs, ays, gamma, 0.0)  # (m, K)
-            return jax.lax.psum(f, axis)                     # replicated
+    def _slow(self, Zb):
+        """(m, K) exact scores of the device rows ``Zb``."""
+        return self._exact_scores(Zb, *self._exact_weights, backend_name=self._backend)
 
-        # check_vma=False: the Pallas rbf_pred call carries no vma type
-        sharded = jax.shard_map(
-            _partial,
-            mesh=mesh,
-            in_specs=(P(), P(axis, None), P(None, axis)),
-            out_specs=P(),
-            check_vma=False,
-        )
-
-        @jax.jit
-        def slow(Zb):
-            return sharded(Zb, Xd, ayd) + jnp.reshape(bias, (1, -1))
-
-        return slow
+    def _slow_step(self, Zp):
+        """The degraded-mode step (circuit breaker open): the exact
+        expansion shaped like ``_step``, with every row's valid False."""
+        return self._exact_step(Zp, *self._exact_weights, backend_name=self._backend,
+                                multiclass=self.multiclass)
 
     # ----------------------------------------------------------- materialize
+
+    def _rescore(self, Z: np.ndarray, idx: np.ndarray) -> np.ndarray:
+        """(len(idx), K) exact scores of the rows ``Z[idx]``.
+
+        At most ``max_batch`` rows per exact call, each gathered into a
+        staging buffer of its bucket's size (tail rows zeroed), so one
+        exact program per bucket compiles, as on the fast path. The
+        buffer keeps the batch's memory order, as ``_enqueue``'s does: a
+        row-major gather from a column-major batch reads across the whole
+        batch for every row."""
+        column_major = Z.strides[0] < Z.strides[1]
+        calls = []
+        for start in range(0, len(idx), self.max_batch):
+            part = idx[start : start + self.max_batch]
+            m = part.shape[0]
+            bkt = bucket_size(m, self.min_bucket, self.max_batch)
+            gather, put, run = self._stage_names(bkt, "fallback")
+            with _annotate(gather):
+                buf = np.empty_like(Z, shape=(bkt, self.d))
+                if column_major:        # the rows are columns of Z.T
+                    np.take(Z.T, part, axis=1, out=buf.T[:, :m], mode="clip")
+                else:
+                    np.take(Z, part, axis=0, out=buf[:m], mode="clip")
+                buf[m:] = 0
+            with _annotate(put):
+                x = self._put(buf)
+            with _annotate(run):
+                calls.append((self._slow(x), m))
+        self.stats.record_fallback(len(idx), len(calls))
+        with _annotate("svm_engine.fallback.sync"):       # device wait + D2H
+            return np.concatenate([np.asarray(out)[:m] for out, m in calls])
 
     def _finalize(self, staged: list | None, chunks):
         """One host sync per result: concat chunks, slice padding, patch
@@ -646,9 +714,8 @@ class SVMEngine:
         if staged is not None and self.allow_fallback and not valid.all():
             idx = np.nonzero(~valid)[0]
             Z = staged[0] if len(staged) == 1 else np.concatenate(staged)
-            self.stats.record_fallback(len(idx))
             with _annotate("svm_engine.fallback"):
-                exact_scores = np.asarray(self._slow(self._put(Z[idx])))  # (m, K)
+                exact_scores = self._rescore(Z, idx)
             scores[idx] = exact_scores
             if self.multiclass:
                 labels[idx] = exact_scores.argmax(axis=-1)

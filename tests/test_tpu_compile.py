@@ -84,13 +84,16 @@ def test_quadform_q8_compiles(one_chip, d, k):
     )
 
 
-@pytest.mark.parametrize("n", [256, 5])
-def test_rbf_pred_compiles(one_chip, n):
+@pytest.mark.parametrize("n,d,k,m", [(256, 780, 10, 8192), (5, 780, 10, 8192),
+                                     (8192, 2000, 1, 36988)], ids=["256", "5", "epsilon"])
+def test_rbf_pred_compiles(one_chip, n, d, k, m):
     # a full bucket (breaker-degraded serving) and a handful of per-row
-    # fallback rows, all ten heads in one pass over 8,192 SVs
+    # fallback rows, all ten heads in one pass over 8,192 SVs; and
+    # epsilon's full bucket at d = 2,000 over its 36,988 SVs, whose
+    # tiles need more than the default scoped VMEM
     _compile(
         lambda Z, X, a, g, b: rbf_predict_pallas(Z, X, a, g, b, config=_cfg("rbf_pred")),
-        [((n, 780), F32), ((8192, 780), F32), ((10, 8192), F32), ((), F32), ((10,), F32)],
+        [((n, d), F32), ((m, d), F32), ((k, m), F32), ((), F32), ((k,), F32)],
         one_chip,
     )
 
