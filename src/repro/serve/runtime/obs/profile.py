@@ -13,8 +13,11 @@ The spans, in nesting order on the dispatching thread:
                                       replica; ``degraded`` on the exact
                                       path)
     runtime.flush.assemble            concatenate the drained requests
+                                      (a flush of two or more)
     svm_engine.pad/b{bkt}             staging copy into the bucket buffer;
-                                      padding rows zeroed
+                                      padding rows zeroed (absent where a
+                                      one-request flush's chunk fills its
+                                      bucket and goes as admitted)
     svm_engine.put/b{bkt}             host -> device issue
     svm_engine.step/{family}/b{bkt}   the jitted call (the enqueue);
                                       ``svm_engine.step_exact/b{bkt}``

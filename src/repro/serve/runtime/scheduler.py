@@ -70,7 +70,7 @@ thread — byte-identical behavior to the pre-replica batcher.
 
 Everything the engine guarantees survives coalescing:
 
-  * **zero steady-state recompiles** — the concatenated rows go through
+  * **zero steady-state recompiles** — the flush's rows go through
     ``engine.submit``'s existing bucket padding, so the flush hits the
     same bounded set of compiled variants (asserted in the throughput
     benchmark via ``jit_cache_size`` before/after);
@@ -256,6 +256,18 @@ class _Pending:
                                           # request's lifecycle spans
 
 
+def _assemble(batch: list[_Pending]) -> tuple[np.ndarray, bool]:
+    """A flush's rows, and whether they are one request's admitted array.
+
+    A one-request flush hands that array on as it is: ``submit`` made it,
+    and nothing writes to it after admission, so the engine may own it
+    (``owned=True``). More requests are concatenated into a new array."""
+    if len(batch) == 1:
+        return batch[0].Z, True
+    with _profile.annotate("runtime.flush.assemble"):
+        return np.concatenate([p.Z for p in batch], axis=0), False
+
+
 class MicroBatcher:
     """Coalesce concurrent ``submit`` calls into bucket-sized engine steps.
 
@@ -371,7 +383,9 @@ class MicroBatcher:
         submission.
         """
         # a copy (in the rows' own memory order): the request waits in the
-        # queue after this returns, and the caller may reuse its array
+        # queue after this returns, and the caller may reuse its array.
+        # Nothing writes to it after this, so a one-request flush hands it
+        # to the engine as its own (``_assemble``)
         Z = np.array(Z, dtype=np.float32)
         if Z.ndim == 1:
             Z = Z[None, :]
@@ -724,10 +738,9 @@ class MicroBatcher:
                         self.faults.check_replica(ENGINE_STEP, replica.index)
                     else:
                         self.faults.check(ENGINE_STEP)
-                with _profile.annotate("runtime.flush.assemble"):
-                    Z = np.concatenate([p.Z for p in batch], axis=0)
+                Z, owned = _assemble(batch)
                 compiled_before = replica.engine.stats.compiled_steps
-                result = replica.engine.submit(Z)
+                result = replica.engine.submit(Z, owned=owned)
                 recompiled = replica.engine.stats.compiled_steps > compiled_before
                 step_attrs = None
                 if tr is not None:
@@ -871,8 +884,8 @@ class MicroBatcher:
                 ), attrs={"reason": "breaker_shed"})
                 return
             try:
-                Z = np.concatenate([p.Z for p in batch], axis=0)
-                result = self.engine.submit_exact(Z)
+                Z, owned = _assemble(batch)
+                result = self.engine.submit_exact(Z, owned=owned)
                 enqueued = [p.t_enqueue for p in batch]
                 telemetry = self.telemetry
 

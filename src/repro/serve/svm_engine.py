@@ -209,6 +209,7 @@ class EngineStats:
     fallback_batches: int = 0           # exact-path calls of the fallback
     compiled_steps: int = 0             # bucket variants traced (compile count)
     padded_instances: int = 0           # wasted rows from bucket padding
+    unstaged_chunks: int = 0            # chunks sent without a staging copy
     degraded_batches: int = 0           # submit_exact batches (breaker open)
     degraded_instances: int = 0
     bucket_hits: dict = dataclasses.field(default_factory=dict)
@@ -216,11 +217,14 @@ class EngineStats:
         default_factory=threading.Lock, repr=False, compare=False
     )
 
-    def record_batch(self, n: int, buckets: list[tuple[int, int]]) -> None:
-        """One submit(): n rows chunked into [(bucket, rows_used), ...]."""
+    def record_batch(self, n: int, buckets: list[tuple[int, int]],
+                     unstaged: int = 0) -> None:
+        """One submit(): n rows chunked into [(bucket, rows_used), ...],
+        ``unstaged`` of the chunks sent to the device as they came."""
         with self._lock:
             self.batches += 1
             self.instances += n
+            self.unstaged_chunks += unstaged
             for bkt, m in buckets:
                 self.padded_instances += bkt - m
                 self.bucket_hits[bkt] = self.bucket_hits.get(bkt, 0) + 1
@@ -231,13 +235,15 @@ class EngineStats:
             self.fallback_instances += k
             self.fallback_batches += calls
 
-    def record_degraded(self, n: int) -> None:
+    def record_degraded(self, n: int, unstaged: int = 0) -> None:
         """One ``submit_exact`` batch of n rows (kept OUT of the fast-path
         batch/instance counters so ``fallback_rate`` — drift's signal —
-        is not polluted by breaker-degraded traffic)."""
+        is not polluted by breaker-degraded traffic), ``unstaged`` of its
+        chunks sent to the device as they came."""
         with self._lock:
             self.degraded_batches += 1
             self.degraded_instances += n
+            self.unstaged_chunks += unstaged
 
     def record_compile(self) -> None:
         with self._lock:
@@ -255,6 +261,7 @@ class EngineStats:
                 "compiled_steps": self.compiled_steps,
                 "padded_instances": self.padded_instances,
                 "padding_overhead": self.padded_instances / max(1, self.instances),
+                "unstaged_chunks": self.unstaged_chunks,
                 "degraded_batches": self.degraded_batches,
                 "degraded_instances": self.degraded_instances,
                 "bucket_hits": dict(self.bucket_hits),
@@ -278,9 +285,9 @@ class EngineResult:
 
     def __init__(self, engine: "SVMEngine", staged: list | None, chunks):
         self._engine = engine
-        self._staged = staged            # the engine's copy of each chunk's
-                                         # rows (fallback re-scores); None
-                                         # when no fallback can happen
+        self._staged = staged            # each chunk's rows as sent, the
+                                         # engine's own (fallback re-scores);
+                                         # None when no fallback can happen
         self._chunks = chunks            # [(scores, valid, labels), n_rows]
         self._done = None
         self._sync = threading.Lock()    # scatter consumers race to be first
@@ -517,10 +524,11 @@ class SVMEngine:
             self._span_names[(bkt, kind)] = names
         return names
 
-    def _enqueue(self, Z, step, *, exact: bool):
+    def _enqueue(self, Z, step, *, exact: bool, owned: bool):
         """Copy each ``max_batch`` chunk of ``Z`` into a staging buffer of
         its bucket's size (padding rows zeroed), copy that to the device
-        and enqueue ``step`` on it; returns (staged rows, chunks).
+        and enqueue ``step`` on it; returns (staged rows, chunks, the
+        number of chunks sent without a staging copy).
 
         The staging buffer keeps the rows' memory order, so the copy is a
         straight one. A column-major batch (what ``np.asarray`` of an
@@ -528,33 +536,53 @@ class SVMEngine:
         the host, while the transfer lays the rows out for the device
         either way. The staging copy is also what lets a caller reuse its
         array as soon as ``submit`` returns: the fallback re-scores from
-        it, never from the caller's array."""
+        it, never from the caller's array.
+
+        With ``owned`` the rows are the engine's once the call returns, so
+        a chunk that fills its bucket and is contiguous (row- or column-
+        major) goes to the device as it is, and the fallback re-scores
+        from it: no padding rows, the same values in the same order."""
         Z = np.asarray(Z, dtype=np.float32)
         if Z.ndim != 2 or Z.shape[1] != self.d:
             raise ValueError(f"expected (n, {self.d}) batch, got {Z.shape}")
-        staged, chunks = [], []
+        staged, chunks, unstaged = [], [], 0
         for start in range(0, max(Z.shape[0], 1), self.max_batch):
             rows = Z[start : start + self.max_batch]
             m = rows.shape[0]
             bkt = bucket_size(m, self.min_bucket, self.max_batch)
             pad, put, run = self._stage_names(bkt, "step_exact" if exact else "step")
-            with _annotate(pad):
-                buf = np.empty_like(rows, shape=(bkt, self.d))
-                buf[:m] = rows
-                buf[m:] = 0
+            if owned and m == bkt and (rows.flags.c_contiguous
+                                       or rows.flags.f_contiguous):
+                buf = rows
+                unstaged += 1
+            else:
+                with _annotate(pad):
+                    buf = np.empty_like(rows, shape=(bkt, self.d))
+                    buf[:m] = rows
+                    buf[m:] = 0
             with _annotate(put):
                 x = self._put(buf)
             with _annotate(run):
                 out = step(x)
             staged.append(buf[:m])
             chunks.append((out, m))
-        return staged, chunks
+        return staged, chunks, unstaged
 
-    def submit(self, Z) -> EngineResult:
-        """Enqueue one batch; returns without waiting for device compute."""
-        staged, chunks = self._enqueue(Z, self._step, exact=False)
+    def submit(self, Z, *, owned: bool = False) -> EngineResult:
+        """Enqueue one batch; returns without waiting for device compute.
+
+        The caller may write to ``Z`` as soon as this returns: each chunk
+        is staged in a buffer of the engine's. ``owned=True`` promises
+        instead that nothing writes to ``Z`` after the call (the micro-
+        batcher passes it for a request's admitted array alone); then a
+        chunk that fills its bucket and is contiguous skips the staging
+        copy (``EngineStats.unstaged_chunks``). The results are the same
+        either way."""
+        staged, chunks, unstaged = self._enqueue(Z, self._step, exact=False,
+                                                 owned=owned)
         self.stats.record_batch(sum(m for _, m in chunks),
-                                [(c[0][0].shape[0], c[1]) for c in chunks])
+                                [(c[0][0].shape[0], c[1]) for c in chunks],
+                                unstaged)
         # the staged rows are only needed to re-score bound-violating rows;
         # don't pin them for every deferred batch when no fallback can happen.
         return EngineResult(self, staged if self.allow_fallback else None,
@@ -565,7 +593,7 @@ class SVMEngine:
         """True when an exact model was published (``submit_exact`` works)."""
         return self._exact_weights is not None
 
-    def submit_exact(self, Z) -> EngineResult:
+    def submit_exact(self, Z, *, owned: bool = False) -> EngineResult:
         """Score ``Z`` entirely through the exact streaming ``rbf_pred``
         path — the circuit breaker's graceful-degradation target.
 
@@ -574,12 +602,14 @@ class SVMEngine:
         ``valid`` False: the rows were exact-served, not approximated.
         Batches are bucket-padded like the fast path so degraded serving
         keeps the bounded-compile property (one slow variant per bucket,
-        not per batch shape). Requires an exact model.
+        not per batch shape). Requires an exact model. ``owned`` is
+        ``submit``'s.
         """
         if self._exact_weights is None:
             raise RuntimeError("submit_exact needs an exact model (none given)")
-        _, chunks = self._enqueue(Z, self._slow_step, exact=True)
-        self.stats.record_degraded(sum(m for _, m in chunks))
+        _, chunks, unstaged = self._enqueue(Z, self._slow_step, exact=True,
+                                            owned=owned)
+        self.stats.record_degraded(sum(m for _, m in chunks), unstaged)
         return EngineResult(self, None, chunks)   # exact already: no re-score
 
     def predict(self, Z) -> tuple[np.ndarray, np.ndarray]:

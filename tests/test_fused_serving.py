@@ -2,7 +2,8 @@
 dispatch, engine shape-bucketing (zero recompiles within a bucket),
 deferred sync, the mesh-sharded exact fallback, and the staging copy
 (padding rows zeroed, the rows' memory order kept, the caller's array
-free once submit returns)."""
+free once submit returns, skipped for a one-request flush's admitted
+rows that fill their bucket)."""
 
 import numpy as np
 import jax
@@ -17,6 +18,7 @@ from repro.kernels.quadform.kernel import quadform_heads_pallas
 from repro.kernels.quadform.ref import quadform_heads_ref
 from repro.core.families import maclaurin
 from repro.serve import PublishSpec, Runtime, svm_engine
+from repro.serve.runtime import ENGINE_STEP, FaultInjector, InjectedFault, scheduler
 from repro.serve.svm_engine import EngineResult, SVMEngine, bucket_size
 from repro.svm import train_lssvm
 from repro.svm.multiclass import (
@@ -301,3 +303,116 @@ def test_caller_may_overwrite_its_rows_once_submit_returns(entry):
     assert not want[1].all()
     np.testing.assert_array_equal(got[0], want[0])
     np.testing.assert_array_equal(got[1], want[1])
+
+
+# ------------------------------ a one-request flush, sent as it was admitted
+
+
+@pytest.fixture(scope="module")
+def flush_runtime():
+    """A runtime over 32- to 128-row buckets whose flush waits for 128
+    rows, or 0.2 s: two requests submitted at once share one flush."""
+    _, m, X = _binary_engine()
+    with Runtime(max_wait_us=200_000, flush_rows=128,
+                 engine_opts=dict(min_bucket=32, max_batch=128)) as rt:
+        rt.publish("m", maclaurin.compile(m), PublishSpec(exact=m))
+        _, eng = rt.registry.get_engine("m")
+        yield rt, eng, X
+
+
+def _flush_through(rt, eng, monkeypatch, requests):
+    """Submit copies of ``requests`` at once, overwriting each copy as its
+    submit returns; returns (the arrays the batcher admitted, the arrays
+    ``_put`` got in the flush, the results)."""
+    admitted, handed = [], []
+    init = scheduler._Pending.__init__
+    monkeypatch.setattr(scheduler._Pending, "__init__",
+                        lambda self, Z, *a, **kw: admitted.append(Z)
+                        or init(self, Z, *a, **kw))
+    put = eng._put
+    monkeypatch.setattr(eng, "_put", lambda buf: handed.append(buf) or put(buf))
+    futs = []
+    for Z in requests:
+        Z = Z.copy(order="K")
+        futs.append(rt.submit("m", Z))
+        Z[:] = 7.0
+    res = [f.result(timeout=60) for f in futs]
+    monkeypatch.undo()                     # the fallback's puts come later
+    return admitted, handed, res
+
+
+def _same_memory(a, b):
+    """``a`` is ``b`` or a view of all of it, in its layout: not a copy."""
+    return (a.ctypes.data, a.shape, a.strides) == (b.ctypes.data, b.shape, b.strides)
+
+
+def _assert_served_as(res, want):
+    """The requests' results, in order, equal ``want`` bit for bit."""
+    assert not want.valid.all()                     # the fallback ran
+    for got, w in zip(("values", "valid", "labels"), (want.values, want.valid,
+                                                       want.labels)):
+        np.testing.assert_array_equal(
+            np.concatenate([getattr(r, got) for r in res]), w)
+
+
+@pytest.mark.parametrize("order", ["C", "F"])
+def test_a_one_request_flush_that_fills_its_bucket_is_sent_as_admitted(
+        flush_runtime, monkeypatch, order):
+    """The batcher's admitted array reaches ``_put`` itself, with no
+    concatenate and no staging copy, and ``unstaged_chunks`` counts it;
+    the values, verdicts and labels, fallback rows included, equal a
+    staged flush's bit for bit."""
+    rt, eng, X = flush_runtime
+    Z = _served_rows(X, 128, order)
+    before = eng.stats.unstaged_chunks
+    admitted, handed, res = _flush_through(rt, eng, monkeypatch, [Z])
+    assert len(admitted) == 1 and len(handed) == 1
+    assert _same_memory(handed[0], admitted[0])
+    assert eng.stats.unstaged_chunks == before + 1
+    assert eng.stats.snapshot()["unstaged_chunks"] == before + 1
+    _assert_served_as(res, _zero_padded_reference(eng, Z))
+
+
+@pytest.mark.parametrize("case", ["coalesced", "partial", "F-longer-than-max_batch"])
+def test_other_flushes_are_staged_as_before(flush_runtime, monkeypatch, case):
+    """Two requests in one flush (their 128 rows fill the bucket), a
+    partial bucket, and the chunks of a column-major request longer than
+    ``max_batch`` are each staged in a bucket buffer of the engine's, and
+    the counter does not move; the results equal the staged path's."""
+    rt, eng, X = flush_runtime
+    Z = _served_rows(X, {"coalesced": 128, "partial": 100}.get(case, 131),
+                     "F" if case.startswith("F") else "C")
+    requests = [Z[:64], Z[64:]] if case == "coalesced" else [Z]
+    before = eng.stats.unstaged_chunks
+    admitted, handed, res = _flush_through(rt, eng, monkeypatch, requests)
+    assert len(admitted) == len(requests)
+    want_buckets = {"coalesced": [128], "partial": [128]}.get(case, [128, 32])
+    assert [buf.shape[0] for buf in handed] == want_buckets
+    assert not any(np.shares_memory(buf, a) for buf in handed for a in admitted)
+    assert eng.stats.unstaged_chunks == before
+    _assert_served_as(res, _zero_padded_reference(eng, Z))
+
+
+def test_a_degraded_one_request_flush_is_sent_as_admitted(monkeypatch):
+    """Every breaker open: the exact path gets the admitted rows as they
+    are too, and serves what ``submit_exact`` serves from staged rows."""
+    _, m, X = _binary_engine()
+    faults = FaultInjector(seed=0)
+    with Runtime(max_wait_us=200_000, flush_rows=128, fault_injector=faults,
+                 breaker=dict(fail_threshold=1, reset_after_s=60.0),
+                 engine_opts=dict(min_bucket=32, max_batch=128)) as rt:
+        rt.publish("m", maclaurin.compile(m), PublishSpec(exact=m))
+        _, eng = rt.registry.get_engine("m")
+        Z = _served_rows(X, 128, "C")
+        faults.fail_next(ENGINE_STEP, 1)
+        with pytest.raises(InjectedFault):
+            rt.predict("m", Z)                          # trips the breaker
+        before = eng.stats.unstaged_chunks
+        admitted, handed, (res,) = _flush_through(rt, eng, monkeypatch, [Z])
+        assert len(handed) == 1 and _same_memory(handed[0], admitted[0])
+        assert eng.stats.unstaged_chunks == before + 1
+        assert eng.stats.degraded_batches == 1
+        want = eng.submit_exact(Z.copy())
+        assert not res.valid.any()
+        for got in ("values", "valid", "labels"):
+            np.testing.assert_array_equal(getattr(res, got), getattr(want, got))

@@ -540,19 +540,25 @@ FLUSH_STAGES = (
 
 
 def test_flush_stage_spans_reach_the_profiler_trace(tmp_path):
+    """Two requests of 2 rows share one flush: the concatenate and the
+    staging copy into bucket 8 both run, and each has its span."""
     import jax
 
     from repro.serve.runtime.obs import profile as obs_profile
 
     m = _svm(0)
     rng = np.random.default_rng(0)
-    with Runtime(engine_opts=ENGINE_OPTS, obs=Observability(seed=5)) as rt:
+    # the flush waits for its bucket's 8 rows, or 50 ms: both requests
+    with Runtime(engine_opts=ENGINE_OPTS, obs=Observability(seed=5),
+                 max_wait_us=50_000) as rt:
         digest = rt.publish("m", maclaurin.compile(m), PublishSpec(exact=m))
         rt.predict("m", _rows(rng, 4))                  # warm the path
         obs_profile.enable(True)
         try:
             with jax.profiler.trace(str(tmp_path / "on")):
-                rt.submit("m", _rows(rng, 4)).result(timeout=30.0).values
+                futs = [rt.submit("m", _rows(rng, 2)) for _ in range(2)]
+                for fut in futs:
+                    fut.result(timeout=30.0).values
         finally:
             obs_profile.enable(False)
         with jax.profiler.trace(str(tmp_path / "off")):
@@ -578,6 +584,32 @@ def test_flush_stage_spans_reach_the_profiler_trace(tmp_path):
 
     off = {e[2] for evs in _host_events(tmp_path / "off").values() for e in evs}
     assert not off & {"runtime.flush", "svm_engine.sync", *FLUSH_STAGES}
+
+
+def test_a_lone_request_that_fills_its_bucket_records_no_copy_spans(tmp_path):
+    """One request of 8 rows, its bucket's size: the flush neither
+    concatenates nor stages, so only put, step and resolve appear."""
+    import jax
+
+    from repro.serve.runtime.obs import profile as obs_profile
+
+    m = _svm(0)
+    rng = np.random.default_rng(0)
+    with Runtime(engine_opts=ENGINE_OPTS) as rt:
+        rt.publish("m", maclaurin.compile(m), PublishSpec(exact=m))
+        rt.predict("m", _rows(rng, 8))                  # warm the path
+        obs_profile.enable(True)
+        try:
+            with jax.profiler.trace(str(tmp_path)):
+                rt.submit("m", _rows(rng, 8)).result(timeout=30.0).values
+        finally:
+            obs_profile.enable(False)
+
+    events = [e for evs in _host_events(tmp_path).values() for e in evs]
+    (flush,) = [e for e in events if e[2] == "runtime.flush"]
+    names = [e[2] for e in sorted(events, key=lambda e: e[0])
+             if flush[0] <= e[0] and e[1] <= flush[1]]
+    assert [n for n in names if n in FLUSH_STAGES] == list(FLUSH_STAGES[2:])
 
 
 def test_degraded_flush_records_its_spans(tmp_path):
