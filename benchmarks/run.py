@@ -27,14 +27,12 @@ def main():
 
     from benchmarks import fig1_error, table1_accuracy, table2_speed
     from benchmarks import table3_modelsize, maclaurin_attn_quality
-    from benchmarks import serving_latency
 
     section("Fig 1 — Maclaurin exp relative error", fig1_error.run)
     section("Table 1 — accuracy / label-diff", table1_accuracy.run)
     section("Table 2 — prediction speed (measured, CPU)", table2_speed.run)
     section("Table 3 — model size", table3_modelsize.run)
     section("Beyond-paper — Maclaurin attention", maclaurin_attn_quality.run)
-    section("Serving — engine latency + fused head scaling", serving_latency.run)
 
     def roofline():
         import glob

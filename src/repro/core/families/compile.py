@@ -26,7 +26,7 @@ import numpy as np
 
 from repro.core.families.base import CompiledArtifact, exact_scores, stack_heads
 from repro.core.rbf import SVMModel
-from repro.kernels.common import autotune
+from repro.kernels.common import autotune, cost
 
 
 @dataclasses.dataclass(frozen=True)
@@ -97,9 +97,9 @@ def compile_model(
     looser budget, or serving the exact model.
 
     ``cost_margin`` enables analytic cost PRE-pruning: once some measured
-    candidate meets the budget, a later candidate whose roofline-predicted
-    cost (``repro.launch.roofline.family_candidate_seconds``) exceeds
-    ``cost_margin`` x the predicted cost of the best budget-meeting
+    candidate meets the budget, a later candidate whose analytically
+    predicted cost (``repro.kernels.common.cost.family_candidate_seconds``)
+    exceeds ``cost_margin`` x the predicted cost of the best budget-meeting
     candidate so far is skipped without compiling or timing it. Predicted
     costs are compared only to OTHER predicted costs (never to measured
     milliseconds — the prior's absolute scale is hardware-fantasy, its
@@ -109,7 +109,6 @@ def compile_model(
     """
     from repro.core import families as _families
     from repro.core.families import quantize
-    from repro.launch import roofline
 
     names = families or tuple(_families.FAMILIES)
     for dt in dtypes:
@@ -134,7 +133,7 @@ def compile_model(
         for dt in dtypes:
             predicted = None
             if cost_margin is not None:
-                predicted = roofline.family_candidate_seconds(
+                predicted = cost.family_candidate_seconds(
                     name, dt, n=n_sample, d=d_in, k=int(k_heads),
                     num_features=opts.get(name, {}).get("num_features"),
                     structured=bool(opts.get(name, {}).get("structured")),

@@ -1,4 +1,3 @@
 from repro.data.synthetic import DATASETS, DatasetSpec, make_dataset
-from repro.data.loader import ShardedLoader, lm_token_batches
 
-__all__ = ["DATASETS", "DatasetSpec", "make_dataset", "ShardedLoader", "lm_token_batches"]
+__all__ = ["DATASETS", "DatasetSpec", "make_dataset"]

@@ -1,7 +1,7 @@
 """Shared tiled-kernel infrastructure for ``repro.kernels.*``.
 
-One layer, three jobs, used by all three kernel families (quadform,
-rbf_pred, maclaurin_attn):
+One layer, used by the kernel families (quadform, rbf_pred, rff_score,
+fwht, maclaurin_attn):
 
   * ``tiles``    — lane/block padding arithmetic (the ``-(-n//b)*b`` that
     used to be hand-rolled per kernel);
@@ -11,7 +11,9 @@ rbf_pred, maclaurin_attn):
     (kernel, platform, shape bucket), backed by the checked-in
     ``tuning_table.json``;
   * ``autotune`` — the sweep harness that produces those measurements
-    (driven by ``benchmarks/serving_latency.py``).
+    (driven by ``tools/sweep_tiles.py``);
+  * ``cost``     — the analytic cost prior that ranks tile and family
+    candidates before anything is measured.
 
 Typical kernel-side use::
 
@@ -24,6 +26,6 @@ Typical kernel-side use::
 """
 
 from repro.kernels.common.config import TileConfig
-from repro.kernels.common import autotune, tiles, tuning
+from repro.kernels.common import autotune, cost, tiles, tuning
 
-__all__ = ["TileConfig", "autotune", "tiles", "tuning"]
+__all__ = ["TileConfig", "autotune", "cost", "tiles", "tuning"]

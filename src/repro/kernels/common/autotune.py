@@ -65,7 +65,7 @@ def prune_candidates(
     """The ``keep`` cheapest-predicted candidates, default ALWAYS kept.
 
     ``prior`` maps a config to a predicted cost (e.g. the analytic
-    roofline terms in ``repro.launch.roofline`` — ``quadform_tile_seconds``
+    terms in ``repro.kernels.common.cost`` — ``quadform_tile_seconds``
     and friends). Pruning only decides what gets MEASURED; keeping the
     default in the measured set preserves autotune's never-worse-than-
     default guarantee even under a badly mis-calibrated prior.

@@ -7,8 +7,8 @@ Resolution order:
   1. in-process overrides (``record(...)`` — what the autotuner and tests
      write);
   2. the checked-in measured table ``tuning_table.json`` next to this
-     module (written back by ``benchmarks/serving_latency.py``'s block
-     sweep, keyed by platform so CPU numbers never leak onto TPU);
+     module (written back by ``tools/sweep_tiles.py``, keyed by platform
+     so CPU numbers never leak onto TPU);
   3. the per-kernel default (the pre-tuning fixed block sizes).
 
 Keys are canonical strings from ``shape_key(d=.., k=.., n=..)`` —
@@ -17,8 +17,9 @@ same bucket. Lookup never fails: an unknown kernel/key quietly falls back
 to ``DEFAULTS``; ``lookup(..., strict=True)`` raises instead (tests).
 
 To add a measured entry by hand, append under
-``entries.<platform>.<kernel>.<key>`` in the JSON (see the benchmark for
-the canonical writer) — or call ``record(...)`` + ``save_table()``.
+``entries.<platform>.<kernel>.<key>`` in the JSON (see
+``tools/sweep_tiles.py`` for the canonical writer) — or call
+``record(...)`` + ``save_table()``.
 """
 
 from __future__ import annotations
@@ -71,7 +72,7 @@ def shape_key(**dims) -> str:
 
     Dimension names are sorted so call-site order never matters. Batch-like
     dimensions should be passed through ``bucket()`` first so every caller
-    lands on the keys the benchmark sweep records.
+    lands on the keys the tile sweep records.
     """
     return "_".join(f"{name}{int(dims[name])}" for name in sorted(dims))
 
@@ -234,7 +235,7 @@ def clear_overrides() -> None:
 def save_table(path: str = TABLE_PATH) -> str:
     """Merge the in-process overrides into the table at ``path`` and write it.
 
-    The benchmark sweep calls this after recording its winners, producing
+    The tile sweep calls this after recording its winners, producing
     the checked-in ``tuning_table.json`` the next process reads back. The
     TARGET file is re-read and merged (never the in-process cache, which
     may belong to a different path); the cached default table is refreshed

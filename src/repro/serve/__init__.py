@@ -20,7 +20,6 @@ without notice.
 """
 
 from repro.core.families import compile_model
-from repro.serve.decode_step import make_prefill_step, make_serve_step
 from repro.serve.runtime import (
     ArtifactCorrupt,
     ArtifactRegistry,
@@ -66,7 +65,5 @@ __all__ = [
     "bucket_size",
     "compile_model",
     "create_app",
-    "make_prefill_step",
-    "make_serve_step",
     "serve",
 ]

@@ -13,9 +13,9 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core import backend, gamma_max
-from repro.core.families import fourier, quantize
+from repro.core.families import fourier, maclaurin, quantize
 from repro.core.rbf import SVMModel
-from repro.kernels.common import tuning
+from repro.kernels.common import cost, tuning
 from repro.kernels.common.config import TileConfig
 from repro.kernels.fwht import (
     fastfood_project,
@@ -26,7 +26,6 @@ from repro.kernels.fwht import (
     fwht,
     fwht_xla,
 )
-from repro.launch import roofline
 from repro.serve.svm_engine import SVMEngine
 
 
@@ -260,6 +259,25 @@ def test_fastfood_pad_heads_is_argmax_neutral(dtype):
     assert fourier.pad_heads(art, 5) is art
 
 
+@pytest.mark.parametrize("dtype", ["float32", "int8"])
+@pytest.mark.parametrize("variant", ["structured", "dense", "quadform"])
+def test_every_variant_serves_a_bucket_without_recompiling(variant, dtype):
+    m = _svm_mc(19, d=20, n_sv=40, k=4)
+    if variant == "quadform":
+        art = maclaurin.compile(m, dtype=dtype)
+    else:
+        art = fourier.compile(
+            m, num_features=64, structured=variant == "structured", dtype=dtype
+        )
+    eng = SVMEngine(art, allow_fallback=False, min_bucket=32, max_batch=32)
+    eng.warmup([32])
+    compiled = eng.jit_cache_size()
+    Z = np.asarray(fourier.holdout_sample(m, 4, 32))
+    for n in (32, 7, 19, 32):
+        assert eng.predict(Z[:n])[0].shape == (n, 4)
+    assert eng.jit_cache_size() == compiled
+
+
 # ----------------------------------------------------------- tuning registry
 
 
@@ -306,25 +324,25 @@ def test_validate_table_drops_unknown_kernel_keeps_fwht():
 
 def test_roofline_structured_prior_undercuts_dense_at_mnist_shape():
     cfg = TileConfig(block_n=256)
-    dense = roofline.rff_tile_seconds(cfg, n=256, d=784, f=2048, k=10)
-    structured = roofline.fwht_tile_seconds(cfg, n=256, d=784, f=2048, k=10)
+    dense = cost.rff_tile_seconds(cfg, n=256, d=784, f=2048, k=10)
+    structured = cost.fwht_tile_seconds(cfg, n=256, d=784, f=2048, k=10)
     assert structured < dense
     # int8 streams fewer bytes than f32 in both forms
-    assert roofline.fwht_tile_seconds(
+    assert cost.fwht_tile_seconds(
         cfg, n=256, d=784, f=2048, k=10, weight_bytes=1
     ) <= structured
     # family_candidate_seconds threads structured= through
-    fd = roofline.family_candidate_seconds(
+    fd = cost.family_candidate_seconds(
         "fourier", "float32", n=256, d=784, k=10, num_features=2048
     )
-    fs = roofline.family_candidate_seconds(
+    fs = cost.family_candidate_seconds(
         "fourier", "float32", n=256, d=784, k=10, num_features=2048,
         structured=True,
     )
     assert fs < fd
     # bigger tiles amortize the streamed readout
-    assert roofline.fwht_tile_seconds(
+    assert cost.fwht_tile_seconds(
         TileConfig(block_n=512), n=1024, d=784, f=2048, k=10
-    ) < roofline.fwht_tile_seconds(
+    ) < cost.fwht_tile_seconds(
         TileConfig(block_n=64), n=1024, d=784, f=2048, k=10
     )

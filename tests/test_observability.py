@@ -249,6 +249,31 @@ def test_runtime_exposes_first_class_gauges_and_spans():
         assert {s["attrs"]["replica"] for s in served} <= {0, 1}
 
 
+def test_an_untraced_runtime_serves_the_same_answers_and_records_nothing():
+    """``obs=False`` turns tracing and metric mirroring off and nothing
+    else: the same requests get the same answers as on a traced runtime,
+    and the telemetry counters still balance."""
+    m = _svm(5)
+    art = maclaurin.compile(m)
+    batches = [_rows(np.random.default_rng(3), n) for n in (3, 8, 1, 5)]
+    answers = {}
+    for traced in (False, True):
+        obs = Observability(seed=0, registry=MetricsRegistry()) if traced else False
+        with Runtime(engine_opts=ENGINE_OPTS, max_wait_us=500.0, obs=obs) as rt:
+            digest = rt.publish("m", art, PublishSpec(exact=m))
+            answers[traced] = [rt.predict("m", Z) for Z in batches]
+            st = rt.stats("m")
+            assert st["requests"] == st["served_requests"] == len(batches)
+            if traced:
+                cons = rt.obs.tracer.conservation(digest[:12])
+                assert cons["served"] == len(batches) and cons["unaccounted"] == 0
+            else:
+                assert rt.obs is None and rt.render_prometheus() == ""
+    for (v_off, ok_off), (v_on, ok_on) in zip(answers[False], answers[True]):
+        np.testing.assert_array_equal(v_off, v_on)
+        np.testing.assert_array_equal(ok_off, ok_on)
+
+
 def _conservation_identities(rt, model, digest, registry):
     """Assert the three-way conservation identity; returns the counts."""
     st = rt.stats(model)

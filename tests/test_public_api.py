@@ -36,8 +36,6 @@ SERVE_ALL = [
     "bucket_size",
     "compile_model",
     "create_app",
-    "make_prefill_step",
-    "make_serve_step",
     "serve",
 ]
 

@@ -10,7 +10,7 @@ import pytest
 
 from repro.core import Budget, CompiledArtifact, backend, compile_model, gamma_max
 from repro.core.families import FAMILIES, get_family, quantize, score_artifact
-from repro.core.families.base import ARTIFACT_FORMAT_VERSION
+from repro.core.families.base import ARTIFACT_FORMAT_VERSION, exact_scores
 from repro.core.rbf import SVMModel
 from repro.serve import PublishSpec
 from repro.serve.svm_engine import SVMEngine
@@ -146,6 +146,16 @@ def test_quant_error_measured_and_reported(family):
     err = np.abs(np.asarray(got) - np.asarray(ref))
     assert np.isclose(err.mean(), q8.meta["quant_mean_abs_err"], rtol=1e-4)
     assert np.isclose(err.max(), q8.meta["quant_max_abs_err"], rtol=1e-4)
+    # against the exact expansion, int8 stays within 0.01 of its parent
+    exact = np.asarray(exact_scores(m, Z))
+    assert np.abs(np.asarray(got) - exact).mean() <= (
+        np.abs(np.asarray(ref) - exact).mean() + 0.01
+    )
+    # and the engine keeps its labels
+    e_f32 = SVMEngine(f32, None, allow_fallback=False)
+    e_q8 = SVMEngine(q8, None, allow_fallback=False)
+    parity = np.mean(e_f32.predict_labels(Z) == e_q8.predict_labels(Z))
+    assert parity >= 0.99, f"{family}: label parity {parity}"
 
 
 def test_v1_artifact_without_dtype_loads_as_float32(tmp_path):

@@ -141,7 +141,10 @@ def test_bounded_queue_sheds_with_retry_after():
     with Runtime(engine_opts=ENGINE_OPTS, fault_injector=fi,
                  max_queue_rows=16, max_wait_us=100.0) as rt:
         rt.publish("m", art, PublishSpec(exact=m))
+        rt.warmup("m")                               # every bucket compiled
         rt.predict("m", _rows(np.random.default_rng(0), 2))  # warm
+        _, eng = rt.registry.get_engine("m")
+        compiled = eng.jit_cache_size()
         rng = np.random.default_rng(1)
         futs, shed = [], 0
         for _ in range(80):
@@ -157,6 +160,10 @@ def test_bounded_queue_sheds_with_retry_after():
         assert st["shed_requests"] == shed
         assert st["requests"] == len(futs) + 1       # shed never enqueued (+warm)
         assert st["queue_rows"] == 0                 # accounting drains to zero
+        # waiting rows are bounded by admission; a popped batch counts
+        # until its flush is recorded, so the high-water is at most 2x
+        assert 0 < st["max_queue_rows"] <= 2 * 16
+        assert eng.jit_cache_size() == compiled      # the burst compiled nothing
 
 
 def test_empty_queue_always_admits_oversized_request():
@@ -263,6 +270,8 @@ def test_breaker_degrades_to_exact_and_recovers():
         rt.publish("m", maclaurin.compile(m), PublishSpec(exact=m))
         rng = np.random.default_rng(0)
         rt.predict("m", _rows(rng, 2))
+        _, eng = rt.registry.get_engine("m")
+        compiled = eng.jit_cache_size()
         fi.fail_next(ENGINE_STEP, 2)
         for _ in range(2):
             with pytest.raises(InjectedFault):
@@ -281,7 +290,10 @@ def test_breaker_degrades_to_exact_and_recovers():
         st = rt.stats("m")
         assert st["breaker"]["degraded_requests"] == 1
         assert st["breaker"]["degraded_rows"] == 5
+        assert st["breaker"]["shed_requests"] == 0   # an exact model serves
         assert st["engine"]["degraded_instances"] == 5
+        # the exact path compiles programs of its own, never a fast bucket
+        assert eng.jit_cache_size() == compiled
         # degraded traffic must not read as drift (validity window clean)
         assert st["fallback_window"]["rows"] == 0 or \
             st["fallback_window"]["invalid"] < st["fallback_window"]["rows"]

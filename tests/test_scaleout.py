@@ -5,10 +5,12 @@ parity). Runs on however many devices the host exposes — one in the
 plain tier-1 suite, eight in CI's forced-host-device step — so every
 assertion here is device-count agnostic.
 
-Also covers the roofline analytic prior: candidate pre-pruning in
+Also covers the analytic cost prior: candidate pre-pruning in
 ``autotune`` (rank-and-prune, default always measured) and cost
 pre-pruning in ``compile_model``.
 """
+
+import threading
 
 import numpy as np
 import jax
@@ -19,9 +21,8 @@ from jax.sharding import Mesh
 from repro.core import gamma_max
 from repro.core.rbf import SVMModel, rbf_kernel
 from repro.core.families import Budget, compile_model, fourier, maclaurin
-from repro.kernels.common import autotune, tuning
+from repro.kernels.common import autotune, cost, tuning
 from repro.kernels.common.config import TileConfig
-from repro.launch import roofline
 from repro.serve import PublishSpec, Runtime
 from repro.serve.runtime import (
     ENGINE_STEP,
@@ -111,6 +112,43 @@ def test_replicated_publish_spreads_flushes_and_conserves():
         assert st["queue_rows"] == 0
         # replicated dispatch keeps the zero-steady-state-recompile law
         assert sum(e.jit_cache_size() for e in engines) == cache_before
+
+
+def test_replicas_dispatch_concurrently():
+    """Two flushes routed to two replicas are inside their dispatch at the
+    same time. The injector's sleep hook holds each flush until both have
+    arrived, which a serial dispatcher can never satisfy (its barrier
+    breaks and the flush fails), and counts the overlap."""
+    arrived = threading.Barrier(2, timeout=30.0)
+    lock = threading.Lock()
+    overlap = {"now": 0, "peak": 0}
+
+    def rendezvous(_seconds):
+        with lock:
+            overlap["now"] += 1
+            overlap["peak"] = max(overlap["peak"], overlap["now"])
+        try:
+            arrived.wait()
+        finally:
+            with lock:
+                overlap["now"] -= 1
+
+    m = _svm(12)
+    fi = FaultInjector(0, sleep=rendezvous)
+    with Runtime(engine_opts=ENGINE_OPTS, fault_injector=fi,
+                 max_wait_us=500.0) as rt:
+        rt.publish("m", maclaurin.compile(m), PublishSpec(exact=m, replicas=2))
+        rng = np.random.default_rng(0)
+        rt.predict("m", _rows(rng, 2))  # warm + build
+        fi.slow_next(ENGINE_STEP, 2)
+        # each request fills max_batch, so each is a flush of its own
+        futs = [rt.submit("m", _rows(rng, 64)) for _ in range(2)]
+        for fut in futs:
+            fut.result(timeout=60.0)
+        st = rt.stats("m")
+    assert overlap["peak"] == 2
+    assert st["failed_requests"] == 0
+    assert all(st["replicas"][i]["flushes"] >= 1 for i in ("0", "1"))
 
 
 def test_replica_fault_trips_only_its_own_breaker():
@@ -409,26 +447,26 @@ def test_runtime_serves_head_sharded_replicas():
 def test_roofline_prior_ranks_bigger_tiles_cheaper():
     small = TileConfig(block_n=64)
     big = TileConfig(block_n=512)
-    t_small = roofline.quadform_tile_seconds(small, n=1024, d=64, k=8)
-    t_big = roofline.quadform_tile_seconds(big, n=1024, d=64, k=8)
+    t_small = cost.quadform_tile_seconds(small, n=1024, d=64, k=8)
+    t_big = cost.quadform_tile_seconds(big, n=1024, d=64, k=8)
     # fewer row-blocks re-stream the stacked Hessian fewer times
     assert t_big < t_small
-    assert roofline.rbf_tile_seconds(big, n=1024, d=64, m=512) < \
-        roofline.rbf_tile_seconds(small, n=1024, d=64, m=512)
+    assert cost.rbf_tile_seconds(big, n=1024, d=64, m=512) < \
+        cost.rbf_tile_seconds(small, n=1024, d=64, m=512)
     # family-level closed forms: int8 streams fewer weight bytes
-    f32 = roofline.family_candidate_seconds("maclaurin", "float32",
-                                            n=256, d=32, k=8)
-    i8 = roofline.family_candidate_seconds("maclaurin", "int8",
-                                           n=256, d=32, k=8)
+    f32 = cost.family_candidate_seconds("maclaurin", "float32",
+                                        n=256, d=32, k=8)
+    i8 = cost.family_candidate_seconds("maclaurin", "int8",
+                                       n=256, d=32, k=8)
     assert i8 < f32
-    assert roofline.family_candidate_seconds("nope", "float32",
-                                             n=256, d=32, k=8) is None
+    assert cost.family_candidate_seconds("nope", "float32",
+                                         n=256, d=32, k=8) is None
 
 
 def test_prune_candidates_keeps_default_under_any_prior():
     default = tuning.DEFAULTS["quadform"]
     cands = [TileConfig(block_n=b) for b in (64, 128, 256)] + [default]
-    prior = lambda cfg: roofline.quadform_tile_seconds(cfg, n=512, d=32, k=4)
+    prior = lambda cfg: cost.quadform_tile_seconds(cfg, n=512, d=32, k=4)
     kept = autotune.prune_candidates(cands, default, prior, keep=1)
     assert default in kept  # never-worse-than-default survives pruning
     assert len(kept) <= 2

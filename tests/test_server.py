@@ -182,7 +182,7 @@ def test_concurrent_clients_coalesce_into_shared_flushes():
     # under requests/2 flushes
     app, h = _app_and_server(max_wait_us=100_000.0)
     with app, h:
-        _publish(app, _svm(0), "det")
+        digest = _publish(app, _svm(0), "det")
         warm = _Client(h.host, h.port)
         warm.request("POST", "/v1/models/det:predict",
                      {"rows": _rows(np.random.default_rng(0), 2)})
@@ -211,6 +211,12 @@ def test_concurrent_clients_coalesce_into_shared_flushes():
         assert st["requests"] == n_clients + 1
         assert burst_flushes <= n_clients // 2, st["flushes"]
         assert st["served_requests"] == n_clients + 1
+        # nothing hangs behind the async bridge, and every request the
+        # wire admitted is accounted for
+        assert st["queue_rows"] == 0
+        cons = app.runtime.obs.tracer.conservation(digest[:12])
+        assert cons["unaccounted"] == 0, cons
+        assert cons["submitted"] == cons["admitted"] + cons["shed"] == n_clients + 1
 
 
 # ------------------------------------------------- overload + Retry-After
