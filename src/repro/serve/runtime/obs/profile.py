@@ -28,9 +28,10 @@ and on whichever thread materializes a result first:
 
   svm_engine.sync                     wait until the outputs are on the host
   svm_engine.fallback                 exact re-score of rows outside Eq 3.11
-    svm_engine.fallback.gather/b{bkt} the rows gathered into a staging
-                                      buffer of their bucket's size
-    svm_engine.fallback.put/b{bkt}    host -> device issue
+    svm_engine.fallback.gather/b{bkt} the enqueue of the rows' gather, on
+                                      the device, into an array of their
+                                      bucket's size (absent where a call
+                                      takes a chunk's device rows whole)
     svm_engine.fallback.step/b{bkt}   the exact program's enqueue
     svm_engine.fallback.sync          device wait and copy back
 

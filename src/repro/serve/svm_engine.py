@@ -68,9 +68,12 @@ Exact fallback (bounded-accuracy serving)
   flash-attention style, never materializing the (n, n_sv) kernel
   matrix). The exact model reaches that program as arguments, never as
   constants: one small program per bucket serves every engine of the
-  same shapes and fits the persistent compile cache. The invalid rows
-  are gathered into a staging buffer of their bucket's size, so any
-  count of them compiles at most one exact program per bucket. With a
+  same shapes and fits the persistent compile cache. The fallback reads
+  the rows the fast step ran on, on the device: a chunk whose rows all
+  fall back goes to the exact program as it is, and other invalid rows
+  are gathered on the device into an array of their bucket's size, so
+  no row crosses to the device twice and any count of them compiles at
+  most one exact program per bucket. With a
   ``mesh``, the support vectors are sharded across devices (shard_map +
   psum over the first mesh axis) so arbitrarily large exact models serve
   the slow path too. The paper recommends
@@ -192,6 +195,13 @@ def _exact_programs(mesh: Mesh | None):
             jax.jit(exact_step, static_argnames=("backend_name", "multiclass")))
 
 
+@jax.jit
+def _gather_rows(x, idx):
+    """The rows ``x[idx]`` of a chunk on the device; an index past the
+    chunk gives a zero row. One program per (chunk bucket, call bucket)."""
+    return jnp.take(x, idx, axis=0, mode="fill", fill_value=0)
+
+
 @dataclasses.dataclass
 class EngineStats:
     """Serving counters, safe under concurrent ``submit()`` callers.
@@ -207,6 +217,7 @@ class EngineStats:
     instances: int = 0
     fallback_instances: int = 0
     fallback_batches: int = 0           # exact-path calls of the fallback
+    fallback_in_place: int = 0          # of them, on a chunk's device rows as sent
     compiled_steps: int = 0             # bucket variants traced (compile count)
     padded_instances: int = 0           # wasted rows from bucket padding
     unstaged_chunks: int = 0            # chunks sent without a staging copy
@@ -229,11 +240,13 @@ class EngineStats:
                 self.padded_instances += bkt - m
                 self.bucket_hits[bkt] = self.bucket_hits.get(bkt, 0) + 1
 
-    def record_fallback(self, k: int, calls: int) -> None:
-        """One result's fallback: k rows re-scored in ``calls`` exact calls."""
+    def record_fallback(self, k: int, calls: int, in_place: int) -> None:
+        """One result's fallback: k rows re-scored in ``calls`` exact calls,
+        ``in_place`` of them on a chunk's device rows as they were sent."""
         with self._lock:
             self.fallback_instances += k
             self.fallback_batches += calls
+            self.fallback_in_place += in_place
 
     def record_degraded(self, n: int, unstaged: int = 0) -> None:
         """One ``submit_exact`` batch of n rows (kept OUT of the fast-path
@@ -258,6 +271,7 @@ class EngineStats:
                 "fallback_instances": self.fallback_instances,
                 "fallback_rate": self.fallback_instances / max(1, self.instances),
                 "fallback_batches": self.fallback_batches,
+                "fallback_in_place": self.fallback_in_place,
                 "compiled_steps": self.compiled_steps,
                 "padded_instances": self.padded_instances,
                 "padding_overhead": self.padded_instances / max(1, self.instances),
@@ -283,12 +297,21 @@ class EngineResult:
     then the exact fallback for rows outside the Eq 3.11 envelope).
     """
 
-    def __init__(self, engine: "SVMEngine", staged: list | None, chunks):
+    def __init__(self, engine: "SVMEngine", rows: list | None, chunks,
+                 sent: list | None = None):
         self._engine = engine
-        self._staged = staged            # each chunk's rows as sent, the
-                                         # engine's own (fallback re-scores);
-                                         # None when no fallback can happen
+        self._rows = rows                # each chunk's device rows, as the
+                                         # fast step ran on them (fallback
+                                         # re-scores); None when no fallback
+                                         # can happen
         self._chunks = chunks            # [(scores, valid, labels), n_rows]
+        # The host arrays sent, never read: held so that the thread that
+        # drops this result frees them, for the batcher the client thread
+        # that made the admitted array. Freed by the flush thread or the
+        # runtime's once their transfer ends, 25.6 MB arrays came back to
+        # the client threads as fresh pages: mnist-bulk on a TPU v5e fell
+        # from 2.4M to 0.95M rows/s.
+        self._sent = sent
         self._done = None
         self._sync = threading.Lock()    # scatter consumers race to be first
         self.on_materialize = None       # scheduler latency hook (fires once)
@@ -304,7 +327,8 @@ class EngineResult:
         # mutates fallback counters — double-running would double-count).
         with self._sync:
             if self._done is None:
-                self._done = self._engine._finalize(self._staged, self._chunks)
+                self._done = self._engine._finalize(self._rows, self._chunks)
+                self._rows = None        # free the device rows once read
                 if self.on_materialize is not None:
                     # the hook receives the finalized (values, valid,
                     # labels) so the scheduler can record per-row validity
@@ -509,14 +533,14 @@ class SVMEngine:
         """Span names of one chunk's three stages, built once per bucket;
         Nones unless profiling is on. ``kind`` is "step" (pad, put, the
         fast step), "step_exact" (pad, put, the degraded step) or
-        "fallback" (gather, put, the exact call)."""
+        "fallback" (the device gather, no put, the exact call)."""
         if _profile_annotation is None:
             return _NO_NAMES
         names = self._span_names.get((bkt, kind))
         if names is None:
             if kind == "fallback":
-                names = tuple(f"svm_engine.fallback.{stage}/b{bkt}"
-                              for stage in ("gather", "put", "step"))
+                names = (f"svm_engine.fallback.gather/b{bkt}", None,
+                         f"svm_engine.fallback.step/b{bkt}")
             else:
                 step = (f"svm_engine.step_exact/b{bkt}" if kind == "step_exact"
                         else f"svm_engine.step/{self.family}/b{bkt}")
@@ -527,25 +551,26 @@ class SVMEngine:
     def _enqueue(self, Z, step, *, exact: bool, owned: bool):
         """Copy each ``max_batch`` chunk of ``Z`` into a staging buffer of
         its bucket's size (padding rows zeroed), copy that to the device
-        and enqueue ``step`` on it; returns (staged rows, chunks, the
-        number of chunks sent without a staging copy).
+        and enqueue ``step`` on it; returns (each chunk's device rows, the
+        host arrays sent, chunks, the number of chunks sent without a
+        staging copy).
 
         The staging buffer keeps the rows' memory order, so the copy is a
         straight one. A column-major batch (what ``np.asarray`` of an
         array on the TPU gives) staged row-major would cost a transpose on
         the host, while the transfer lays the rows out for the device
         either way. The staging copy is also what lets a caller reuse its
-        array as soon as ``submit`` returns: the fallback re-scores from
-        it, never from the caller's array.
+        array as soon as ``submit`` returns. The fallback re-scores from
+        the device rows, never from a host array.
 
         With ``owned`` the rows are the engine's once the call returns, so
         a chunk that fills its bucket and is contiguous (row- or column-
-        major) goes to the device as it is, and the fallback re-scores
-        from it: no padding rows, the same values in the same order."""
+        major) goes to the device as it is: no padding rows, the same
+        values in the same order."""
         Z = np.asarray(Z, dtype=np.float32)
         if Z.ndim != 2 or Z.shape[1] != self.d:
             raise ValueError(f"expected (n, {self.d}) batch, got {Z.shape}")
-        staged, chunks, unstaged = [], [], 0
+        xs, sent, chunks, unstaged = [], [], [], 0
         for start in range(0, max(Z.shape[0], 1), self.max_batch):
             rows = Z[start : start + self.max_batch]
             m = rows.shape[0]
@@ -564,9 +589,10 @@ class SVMEngine:
                 x = self._put(buf)
             with _annotate(run):
                 out = step(x)
-            staged.append(buf[:m])
+            xs.append(x)
+            sent.append(buf)
             chunks.append((out, m))
-        return staged, chunks, unstaged
+        return xs, sent, chunks, unstaged
 
     def submit(self, Z, *, owned: bool = False) -> EngineResult:
         """Enqueue one batch; returns without waiting for device compute.
@@ -578,15 +604,15 @@ class SVMEngine:
         chunk that fills its bucket and is contiguous skips the staging
         copy (``EngineStats.unstaged_chunks``). The results are the same
         either way."""
-        staged, chunks, unstaged = self._enqueue(Z, self._step, exact=False,
-                                                 owned=owned)
+        xs, sent, chunks, unstaged = self._enqueue(Z, self._step, exact=False,
+                                                   owned=owned)
         self.stats.record_batch(sum(m for _, m in chunks),
                                 [(c[0][0].shape[0], c[1]) for c in chunks],
                                 unstaged)
-        # the staged rows are only needed to re-score bound-violating rows;
+        # the device rows are only needed to re-score bound-violating rows;
         # don't pin them for every deferred batch when no fallback can happen.
-        return EngineResult(self, staged if self.allow_fallback else None,
-                            chunks)
+        return EngineResult(self, xs if self.allow_fallback else None, chunks,
+                            sent)
 
     @property
     def exact_available(self) -> bool:
@@ -607,8 +633,8 @@ class SVMEngine:
         """
         if self._exact_weights is None:
             raise RuntimeError("submit_exact needs an exact model (none given)")
-        _, chunks, unstaged = self._enqueue(Z, self._slow_step, exact=True,
-                                            owned=owned)
+        _, _, chunks, unstaged = self._enqueue(Z, self._slow_step, exact=True,
+                                               owned=owned)
         self.stats.record_degraded(sum(m for _, m in chunks), unstaged)
         return EngineResult(self, None, chunks)   # exact already: no re-score
 
@@ -693,40 +719,47 @@ class SVMEngine:
 
     # ----------------------------------------------------------- materialize
 
-    def _rescore(self, Z: np.ndarray, idx: np.ndarray) -> np.ndarray:
-        """(len(idx), K) exact scores of the rows ``Z[idx]``.
+    def _rescore(self, xs: list, chunks, idx: np.ndarray) -> np.ndarray:
+        """(len(idx), K) exact scores of the result's rows ``idx``, read
+        from ``xs``, the device rows the fast step ran on.
 
-        At most ``max_batch`` rows per exact call, each gathered into a
-        staging buffer of its bucket's size (tail rows zeroed), so one
-        exact program per bucket compiles, as on the fast path. The
-        buffer keeps the batch's memory order, as ``_enqueue``'s does: a
-        row-major gather from a column-major batch reads across the whole
-        batch for every row."""
-        column_major = Z.strides[0] < Z.strides[1]
-        calls = []
-        for start in range(0, len(idx), self.max_batch):
-            part = idx[start : start + self.max_batch]
+        At most ``max_batch`` rows per exact call, as on the fast path. A
+        call whose rows are all of one chunk's runs on that chunk's array
+        as it is: its bucket is the call's, so there is no gather and no
+        copy. Any other call's rows are gathered on the device into an
+        array of their bucket's size (tail rows zero), one ``take`` per
+        chunk they come from, so one exact program per bucket compiles."""
+        starts = np.cumsum([0] + [m for _, m in chunks])
+        calls, in_place = [], 0
+        for lo in range(0, len(idx), self.max_batch):
+            part = idx[lo : lo + self.max_batch]
             m = part.shape[0]
             bkt = bucket_size(m, self.min_bucket, self.max_batch)
-            gather, put, run = self._stage_names(bkt, "fallback")
-            with _annotate(gather):
-                buf = np.empty_like(Z, shape=(bkt, self.d))
-                if column_major:        # the rows are columns of Z.T
-                    np.take(Z.T, part, axis=1, out=buf.T[:, :m], mode="clip")
-                else:
-                    np.take(Z, part, axis=0, out=buf[:m], mode="clip")
-                buf[m:] = 0
-            with _annotate(put):
-                x = self._put(buf)
+            gather, _, run = self._stage_names(bkt, "fallback")
+            owner = np.searchsorted(starts, part, side="right") - 1
+            c = owner[0]
+            if owner[-1] == c and m == starts[c + 1] - starts[c]:
+                x = xs[c]                   # every row of chunk c, in order
+                in_place += 1
+            else:
+                with _annotate(gather):
+                    x = None
+                    for c in np.unique(owner):
+                        mine = owner == c
+                        local = np.full(bkt, xs[c].shape[0], np.int32)  # zero rows
+                        local[:m][mine] = part[mine] - starts[c]
+                        rows = _gather_rows(xs[c], local)
+                        x = rows if x is None else x + rows     # + 0: exact
             with _annotate(run):
                 calls.append((self._slow(x), m))
-        self.stats.record_fallback(len(idx), len(calls))
+        self.stats.record_fallback(len(idx), len(calls), in_place)
         with _annotate("svm_engine.fallback.sync"):       # device wait + D2H
             return np.concatenate([np.asarray(out)[:m] for out, m in calls])
 
-    def _finalize(self, staged: list | None, chunks):
+    def _finalize(self, xs: list | None, chunks):
         """One host sync per result: concat chunks, slice padding, patch
-        bound-violating rows through the exact path."""
+        bound-violating rows through the exact path, from ``xs``, each
+        chunk's device rows."""
         with _annotate("svm_engine.sync"):            # device wait + D2H
             scores = np.concatenate(
                 [np.asarray(out[0])[:m] for out, m in chunks]
@@ -741,11 +774,10 @@ class SVMEngine:
             # correct — only the score columns need slicing back down.
             scores = np.ascontiguousarray(scores[:, : self.num_heads])
 
-        if staged is not None and self.allow_fallback and not valid.all():
+        if xs is not None and self.allow_fallback and not valid.all():
             idx = np.nonzero(~valid)[0]
-            Z = staged[0] if len(staged) == 1 else np.concatenate(staged)
             with _annotate("svm_engine.fallback"):
-                exact_scores = self._rescore(Z, idx)
+                exact_scores = self._rescore(xs, chunks, idx)
             scores[idx] = exact_scores
             if self.multiclass:
                 labels[idx] = exact_scores.argmax(axis=-1)

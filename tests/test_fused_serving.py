@@ -3,7 +3,11 @@ dispatch, engine shape-bucketing (zero recompiles within a bucket),
 deferred sync, the mesh-sharded exact fallback, and the staging copy
 (padding rows zeroed, the rows' memory order kept, the caller's array
 free once submit returns, skipped for a one-request flush's admitted
-rows that fill their bucket)."""
+rows that fill their bucket, and held by the result until it is
+dropped)."""
+
+import gc
+import weakref
 
 import numpy as np
 import jax
@@ -226,15 +230,15 @@ def _zero_padded_reference(eng, Z):
     """``eng``'s result for ``Z`` with each chunk staged in a row-major
     ``np.zeros`` buffer of its bucket's size."""
     Z = np.ascontiguousarray(Z, np.float32)
-    staged, chunks = [], []
+    xs, chunks = [], []
     for start in range(0, Z.shape[0], eng.max_batch):
         rows = Z[start:start + eng.max_batch]
         bkt = bucket_size(len(rows), eng.min_bucket, eng.max_batch)
         buf = np.zeros((bkt, eng.d), np.float32)
         buf[:len(rows)] = rows
-        staged.append(rows)
-        chunks.append((eng._step(eng._put(buf)), len(rows)))
-    return EngineResult(eng, staged, chunks)
+        xs.append(eng._put(buf))
+        chunks.append((eng._step(xs[-1]), len(rows)))
+    return EngineResult(eng, xs, chunks)
 
 
 @pytest.fixture(scope="module")
@@ -371,6 +375,29 @@ def test_a_one_request_flush_that_fills_its_bucket_is_sent_as_admitted(
     assert eng.stats.unstaged_chunks == before + 1
     assert eng.stats.snapshot()["unstaged_chunks"] == before + 1
     _assert_served_as(res, _zero_padded_reference(eng, Z))
+
+
+@pytest.mark.parametrize("owned", [True, False], ids=["as-admitted", "staged"])
+def test_the_rows_sent_live_until_the_result_is_dropped(owned):
+    """The host array a chunk was sent from lives as long as its result,
+    read or not, and however soon its transfer ends: the thread that
+    drops the result frees it (for the batcher, the client thread that
+    made the admitted array)."""
+    eng, _, X = _binary_engine(min_bucket=32, max_batch=128)
+    Z = _served_rows(X, 128 if owned else 100, "C")
+    sent = []
+    put = eng._put
+    eng._put = lambda buf: sent.append(weakref.ref(buf)) or put(buf)
+    r = eng.submit(Z, owned=owned)
+    del eng._put
+    assert np.shares_memory(sent[0](), Z) == owned
+    del Z
+    assert not r.valid.all()                        # read, fallback rows too
+    gc.collect()
+    assert sent[0]() is not None
+    del r
+    gc.collect()
+    assert sent[0]() is None
 
 
 @pytest.mark.parametrize("case", ["coalesced", "partial", "F-longer-than-max_batch"])
